@@ -7,6 +7,7 @@ import os
 import tempfile
 from pathlib import Path
 
+from repro.compile_cache import enable_compile_cache
 from repro.graph.generate import rmat_edges, materialize
 from repro.graph.preprocess import preprocess_graph
 from repro.graph.storage import GraphStore, write_edge_list
@@ -15,11 +16,8 @@ BENCH_DIR = Path(os.environ.get("BENCH_DIR", tempfile.gettempdir())) / "repro_be
 SCALE = int(os.environ.get("BENCH_SCALE", "16"))          # 2^16 = 65k vertices
 EDGE_FACTOR = int(os.environ.get("BENCH_EDGE_FACTOR", "16"))  # ~1M edges
 
-# persistent jit cache: shard-step compiles amortize across bench processes
-import jax  # noqa: E402
-
-jax.config.update("jax_compilation_cache_dir", str(BENCH_DIR / "jit_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+# persistent jit cache: shard-step compiles amortize across bench runs
+enable_compile_cache()
 
 
 def get_graph():

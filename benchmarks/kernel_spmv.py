@@ -39,9 +39,9 @@ def spmv_variants(use_pallas="auto", reps: int = 3) -> list[dict]:
 
     ``model_bytes`` is the minimum HBM traffic of the path actually taken
     (``describe_dispatch``): edge arrays once (cols int32 + vals at their
-    *stored* dtype — the quantization win), sources once, partials out.  The
-    unfused paths additionally materialize the gathered [R, W, K] matrix
-    (one write + one read).  Achieved bandwidth = model_bytes / seconds, an
+    *stored* dtype — the quantization win), sources once, partials out, plus
+    the gathered [K, R, W] matrix every path materializes (one write + one
+    read).  Achieved bandwidth = model_bytes / seconds, an
     *upper bound* on usefully-moved bytes — honest for compiled backends,
     pessimistic in interpret mode (which is why the report prints the path).
     """
@@ -63,16 +63,15 @@ def spmv_variants(use_pallas="auto", reps: int = 3) -> list[dict]:
                 f = lambda: ell_spmv_batch(x, cols, vals, row_map, _R,
                                            "min_plus", use_pallas=use_pallas,
                                            qparams=qp)
-            path = describe_dispatch(use_pallas, n=_N, k=k)
+            path = describe_dispatch(use_pallas, k=k)
             jax.block_until_ready(f())  # compile
             t0 = time.perf_counter()
             for _ in range(reps):
                 jax.block_until_ready(f())
             dt = (time.perf_counter() - t0) / reps
             model_bytes = (cols_np.nbytes + q.nbytes        # edge pass
-                           + _N * k * 4 + _R * k * 4)       # sources + out
-            if "fused" not in path:
-                model_bytes += 2 * _R * _W * k * 4          # gathered matrix
+                           + _N * k * 4 + _R * k * 4        # sources + out
+                           + 2 * _R * _W * k * 4)           # gathered matrix
             out.append(dict(dtype=dtype, k=k, seconds=dt,
                             model_bytes=model_bytes, path=path))
     return out

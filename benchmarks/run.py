@@ -1,4 +1,6 @@
 # One function per paper table/figure. Prints ``name,us_per_call,derived`` CSV.
+# Every module runs in this one process: a process that has touched JAX owns
+# the accelerator, so no module may start a child that needs JAX.
 from __future__ import annotations
 
 import sys
@@ -11,8 +13,8 @@ def main() -> None:
                             fig_app_zoo, fig_autotune, fig_batch_frontiers,
                             fig_cache_tiers, fig_delta_incremental,
                             fig_multidevice, fig_pipeline_overlap,
-                            fig_serve_throughput, grad_compression,
-                            kernel_spmv, roofline_report, table2_compression,
+                            fig_serve_throughput, kernel_spmv,
+                            roofline_report, table2_compression,
                             table3_io_model, table5_apps, table8_preprocessing)
     modules = [
         ("table2_compression", table2_compression),
@@ -31,7 +33,6 @@ def main() -> None:
         ("fig_delta_incremental", fig_delta_incremental),
         ("fig_autotune", fig_autotune),
         ("kernel_spmv", kernel_spmv),
-        ("grad_compression", grad_compression),
         ("roofline_report", roofline_report),
     ]
     print("name,us_per_call,derived")

@@ -11,8 +11,6 @@ preprocess once, run many applications over a shared compressed cache),
     with GraphSession(store, cache_budget_bytes=1 << 28) as s:
         pr = s.run("pagerank", max_iters=30)
 """
-import repro._compat  # noqa: F401  (jax version bridge; must import first)
-
 # lazy attribute exports (PEP 562) keep `import repro` light — jax-heavy
 # modules load on first touch of the corresponding name.
 _EXPORTS = {

@@ -51,16 +51,6 @@ class Semiring:
             return jnp.max(contrib, axis=axis)
         return jnp.min(contrib, axis=axis)
 
-    def fold_batch(self, edge_vals: Array, src_vals: Array, mask: Array) -> Array:
-        """Batched fold: one edge pass serves K value columns.
-
-        edge_vals/mask are [R, W] (shared by every column); src_vals carries a
-        trailing batch axis [R, W, K].  Reduces the ELL width dim -> [R, K].
-        All four semirings broadcast: COMBINE sees edge [R, W, 1] against
-        source [R, W, K], so the edge data is read once however large K is.
-        """
-        return self.fold(edge_vals[..., None], src_vals, mask[..., None], axis=1)
-
 
 PLUS_TIMES = Semiring(
     name="plus_times",

@@ -27,7 +27,6 @@ from typing import Any, Mapping
 
 import numpy as np
 
-import repro._compat  # noqa: F401  (jax.shard_map/AxisType aliases)
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -29,14 +29,20 @@ def rmat_edges(
     """
     n_edges = edge_factor << scale
     rng = np.random.default_rng(seed)
-    probs = np.array([a, b, c, 1.0 - a - b - c])
+    # the quadrant draw of Generator.choice(4, p=[a, b, c, d]) — same cdf,
+    # same uniform stream, same side="right" search — as three compares,
+    # which is several times cheaper than choice() on 4M-edge chunks
+    cdf = np.array([a, b, c, 1.0 - a - b - c]).cumsum()
+    cdf /= cdf[-1]
     emitted = 0
     while emitted < n_edges:
         m = min(chunk, n_edges - emitted)
         src = np.zeros(m, dtype=np.int64)
         dst = np.zeros(m, dtype=np.int64)
         for _ in range(scale):
-            q = rng.choice(4, size=m, p=probs)
+            u = rng.random(m)
+            q = ((u >= cdf[0]).astype(np.int8) + (u >= cdf[1])
+                 + (u >= cdf[2]))
             src = (src << 1) | (q >> 1)
             dst = (dst << 1) | (q & 1)
         yield src, dst

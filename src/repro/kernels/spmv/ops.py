@@ -5,19 +5,19 @@ kernels are known-correct compiled (tpu — see ``_COMPILED_BACKENDS`` for why
 that list is TPU-only) compile them; everything else runs them in interpret
 mode.  ``use_pallas`` selects the family:
 
-  * ``"auto"``  — fastest correct path per platform.  Compiled backends take
-    Pallas (fused gather→fold when the [n, K] frontier fits VMEM, otherwise
-    XLA-gather + native batched fold).  On CPU the single-column path keeps
-    Pallas in interpret mode (cheap enough, keeps the lowering exercised)
-    but the BATCHED [n, K] fold falls back to pure jnp — interpret mode
-    executes the grid step-by-step in Python with cost scaling in K, which
-    would erase exactly the amortization ``run_batch``/GraphService exist
-    for.  Non-CPU interpreting backends (gpu, until the kernels are ported)
-    demote to jnp for every K: the jnp path is fully XLA-compiled there,
-    while interpret mode would be step-by-step Python.  The demotion applies
-    only when *interpreting*, never on a compiled backend.
-  * ``True``    — force Pallas (interpret on CPU; the A/B referee tests use
-    this), including the fused kernel when the frontier fits.
+  * ``"auto"``  — fastest correct path per platform.  TPU compiles the
+    Pallas kernels for every K: XLA gathers the sources, then the fold
+    kernel (K = 1) or the batched fold kernel (K > 1) reduces them.  On CPU
+    the single-column path keeps Pallas in interpret mode (cheap enough,
+    keeps the lowering exercised) but the BATCHED [n, K] fold takes pure
+    jnp — interpret mode executes the grid step-by-step in Python with cost
+    scaling in K, which would erase exactly the amortization
+    ``run_batch``/GraphService exist for.  Non-CPU interpreting backends
+    (gpu, until the kernels are ported) take jnp for every K: the jnp path
+    is fully XLA-compiled there, while interpret mode would be step-by-step
+    Python.  These demotions apply only when *interpreting*, never on TPU.
+  * ``True``    — force Pallas (interpret off TPU; the A/B referee tests
+    use this).
   * ``False``   — force the pure-jnp oracle path.
 
 Quantized edge values (int8/float16 + affine qparams) are dequantized
@@ -28,7 +28,6 @@ path a given configuration takes (used by the roofline report and docs).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -41,14 +40,9 @@ from repro.kernels.spmv import spmv as _pallas
 # every kernel in spmv.py accumulates into a revisited out_ref across the W
 # grid axis (pl.when(w_step != 0) read-modify-write), which is only safe
 # because TPU executes the grid sequentially.  GPU backends (cuda/rocm/
-# triton) run grid programs in parallel, so that accumulation races — and
-# the in-kernel jnp.take gather has no Triton lowering.  Do not add a GPU
-# backend here until the kernels are ported to (and tested on) one.
+# triton) run grid programs in parallel, so that accumulation races.  Do not
+# add a GPU backend here until the kernels are ported to (and tested on) one.
 _COMPILED_BACKENDS = ("tpu",)
-
-# The fused gather→fold kernel keeps the whole [n, K] source matrix resident
-# in VMEM; frontiers bigger than this fall back to XLA-gather + batched fold.
-FUSED_X_BYTES_LIMIT = int(os.environ.get("GRAPHMP_FUSED_VMEM", 4 << 20))
 
 
 def _resolve(use_pallas) -> tuple[bool, bool]:
@@ -65,69 +59,44 @@ def _resolve(use_pallas) -> tuple[bool, bool]:
     return True, jax.default_backend() not in _COMPILED_BACKENDS
 
 
-def _auto_demotes(use_pallas, interp: bool, k: int) -> bool:
-    """Should an interpreting "auto" call take the jnp path instead?
-
-    Interpret mode earns its keep only as the cheap single-column CPU
-    referee path.  Batched folds demote (interpret cost scales with K), and
-    so does every non-CPU interpreting backend (gpu): there the jnp path is
-    fully XLA-compiled while interpret mode is step-by-step Python.
-    """
-    if use_pallas != "auto" or not interp:
-        return False
-    return k > 1 or jax.default_backend() != "cpu"
-
-
-def _fused_fits(n: int, k: int, itemsize: int = 4) -> bool:
-    """True when the [n, K] frontier's VMEM footprint fits the fused gate.
-
-    Footprint is the *padded* block size: VMEM tiles the two minor dims to
-    (8 sublane, 128 lane), so a K=1 column really occupies 128 lanes per
-    row — n*k*itemsize would under-count that case by 128x and admit
-    frontiers that cannot compile on TPU.
-    """
-    return _pallas.vmem_block_bytes((n, k), itemsize) <= FUSED_X_BYTES_LIMIT
-
-
-def _pick_path(use_pallas, n: int, k: int, itemsize: int = 4) -> tuple[str, bool]:
-    """-> (path, interpret) with path in {'jnp', 'pallas-fold', 'pallas-fused'}.
+def _pick_path(use_pallas, k: int) -> tuple[bool, bool]:
+    """-> (use the Pallas fold kernels?, interpret) for a K-column call.
 
     The spmv dispatch table (docs/ARCHITECTURE.md "Kernels"):
-      * jnp            — use_pallas=False anywhere, or "auto" on an
-        interpreting backend with K > 1 or off-CPU (the interpret
-        demotions; see ``_auto_demotes``).
-      * pallas-fused   — compiled backends (and forced ``True``) when the
-        [n, K] frontier fits FUSED_X_BYTES_LIMIT.
-      * pallas-fold    — everything else on the Pallas family: XLA gather +
-        fold kernel (single-column CPU "auto" stays here, preserving the
-        cheap interpret referee path).
+      * jnp    — use_pallas=False anywhere, or "auto" on an interpreting
+        backend with K > 1 or off-CPU (interpret mode earns its keep only as
+        the cheap single-column CPU referee; on GPU the jnp path is fully
+        XLA-compiled while interpret mode is step-by-step Python).
+      * pallas — everything else: XLA gather + the fold kernel (K = 1) or
+        the batched fold kernel (K > 1); compiled on TPU, interpreted
+        elsewhere.
     """
     use, interp = _resolve(use_pallas)
+    if use and use_pallas == "auto" and interp \
+            and (k > 1 or jax.default_backend() != "cpu"):
+        use = False
+    return use, interp and use
+
+
+def describe_dispatch(use_pallas="auto", *, k: int = 1) -> str:
+    """Human-readable path ``ell_spmv``/``ell_spmv_batch`` takes for K
+    columns on this process's default backend: ``jnp`` |
+    ``pallas:<mode>:gather+fold``."""
+    use, interp = _pick_path(use_pallas, k)
     if not use:
-        return "jnp", False
-    if _auto_demotes(use_pallas, interp, k):
-        return "jnp", False
-    if _fused_fits(n, k, itemsize) and (use_pallas is True or not interp):
-        return "pallas-fused", interp
-    return "pallas-fold", interp
-
-
-def describe_dispatch(use_pallas="auto", *, n: int, k: int = 1,
-                      itemsize: int = 4) -> str:
-    """Human-readable path ``ell_spmv``/``ell_spmv_batch`` takes on this
-    process's default backend: ``jnp`` | ``pallas:<mode>:<kernel>``."""
-    path, interp = _pick_path(use_pallas, n, k, itemsize)
-    if path == "jnp":
         return "jnp"
-    mode = "interpret" if interp else "compiled"
-    kernel = "fused" if path == "pallas-fused" else "gather+fold"
-    return f"pallas:{mode}:{kernel}"
+    return f"pallas:{'interpret' if interp else 'compiled'}:gather+fold"
+
+
+def _safe(cols):
+    """Padded slots (cols < 0) gather row 0; the fold masks them out."""
+    return jnp.where(cols >= 0, cols, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("semiring", "use_pallas"))
 def ell_fold(xg, vals, cols, semiring: str, use_pallas="auto", qparams=None):
-    use, interp = _resolve(use_pallas)
-    if use and not _auto_demotes(use_pallas, interp, 1):
+    use, interp = _pick_path(use_pallas, 1)
+    if use:
         return _pallas.ell_fold_pallas(xg, vals, cols, semiring,
                                        interpret=interp, qparams=qparams)
     return _ref.ell_fold_ref(xg, _ref.maybe_dequantize(vals, qparams), cols,
@@ -137,13 +106,9 @@ def ell_fold(xg, vals, cols, semiring: str, use_pallas="auto", qparams=None):
 @functools.partial(jax.jit, static_argnames=("semiring", "use_pallas"))
 def ell_gather_fold(x_blk, cols, vals, semiring: str, use_pallas="auto",
                     qparams=None):
-    use, interp = _resolve(use_pallas)
-    if use and not _auto_demotes(use_pallas, interp, 1):
-        return _pallas.ell_gather_fold_pallas(x_blk, cols, vals, semiring,
-                                              interpret=interp, qparams=qparams)
-    return _ref.ell_gather_fold_ref(x_blk, cols,
-                                    _ref.maybe_dequantize(vals, qparams),
-                                    semiring)
+    """2-D-tiled fold: cols index a local source block x_blk [VB]."""
+    return ell_fold(x_blk[_safe(cols)], vals, cols, semiring,
+                    use_pallas=use_pallas, qparams=qparams)
 
 
 @functools.partial(jax.jit, static_argnames=("semiring", "num_segments", "use_pallas"))
@@ -153,21 +118,13 @@ def ell_spmv(x, cols, vals, row_map, num_segments: int, semiring: str,
 
     x: [n] resident source array; returns [num_segments] partials for the
     shard's destination interval (identity where the interval has no edges).
-    On the fused path the gather happens inside the kernel against the
-    VMEM-resident frontier; otherwise XLA gathers from HBM first.
     """
-    path, interp = _pick_path(use_pallas, x.shape[0], 1, x.dtype.itemsize)
-    if path == "jnp":
+    use, interp = _pick_path(use_pallas, 1)
+    if not use:
         return _ref.ell_spmv_ref(x, cols, _ref.maybe_dequantize(vals, qparams),
                                  row_map, num_segments, semiring)
-    if path == "pallas-fused":
-        partials = _pallas.ell_spmv_fused_pallas(
-            x[:, None], cols, vals, semiring, interpret=interp, qparams=qparams)
-    else:
-        # masking is handled inside the fold via cols>=0; clamp for a safe gather
-        xg = x[jnp.where(cols >= 0, cols, 0)]
-        partials = _pallas.ell_fold_pallas(xg, vals, cols, semiring,
-                                           interpret=interp, qparams=qparams)
+    partials = _pallas.ell_fold_pallas(x[_safe(cols)], vals, cols, semiring,
+                                       interpret=interp, qparams=qparams)
     return _ref.segment_combine(partials, row_map, num_segments, semiring)
 
 
@@ -177,22 +134,16 @@ def ell_spmv_batch(x, cols, vals, row_map, num_segments: int, semiring: str,
     """Batched shard update: one edge pass serves K frontiers.
 
     x: [n, K] resident source matrix; returns [num_segments, K] partials —
-    column k is exactly ``ell_spmv(x[:, k], ...)``.  The fused path keeps x
-    VMEM-resident and never materializes the [R, W, K] gathered matrix in
-    HBM; the fold path gathers once in XLA and feeds the kernel the native
-    [R, W, K] layout (no transpose round-trip).
+    column k is exactly ``ell_spmv(x[:, k], ...)``.  Both paths gather
+    column-major ([K, R, W]), so each column folds as a plain [R, W] tile
+    (the Pallas kernel against one load of the edge tile).
     """
-    n, k = x.shape
-    path, interp = _pick_path(use_pallas, n, k, x.dtype.itemsize)
-    if path == "jnp":
-        xg = x[jnp.where(cols >= 0, cols, 0)]      # [R, W, K]
-        partials = _ref.ell_fold_batch_ref(xg, _ref.maybe_dequantize(vals, qparams),
-                                           cols, semiring)
-    elif path == "pallas-fused":
-        partials = _pallas.ell_spmv_fused_pallas(
-            x, cols, vals, semiring, interpret=interp, qparams=qparams)
-    else:
-        xg = x[jnp.where(cols >= 0, cols, 0)]      # [R, W, K]
+    use, interp = _pick_path(use_pallas, x.shape[1])
+    xg = x.T[:, _safe(cols)]                      # [K, R, W], one gather
+    if use:
         partials = _pallas.ell_fold_batch_pallas(
             xg, vals, cols, semiring, interpret=interp, qparams=qparams)
+    else:
+        partials = _ref.ell_fold_batch_ref(
+            xg, _ref.maybe_dequantize(vals, qparams), cols, semiring)
     return _ref.segment_combine_batch(partials, row_map, num_segments, semiring)

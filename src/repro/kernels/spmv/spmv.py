@@ -3,26 +3,19 @@
 GraphMP's per-shard update — "pull source values, combine along in-edges,
 reduce per destination" — is the compute hot-spot of the whole system.  On
 TPU we lay shards out as blocked-ELL (DESIGN.md §2/§4) and fuse
-mask→combine→reduce in VMEM:
+mask→combine→reduce in VMEM.  Sources are always pre-gathered by XLA (the
+HBM gather is XLA-native; an in-kernel gather from VMEM does not lower on
+TPU Mosaic), and the kernels fold the gathered tiles:
 
-  * ``ell_fold_pallas``        — sources pre-gathered by XLA (HBM gather is
-    XLA-native); kernel folds [R, W] tiles to [R, 1] partials.  Grid is
+  * ``ell_fold_pallas``        — [R, W] tiles to [R, 1] partials.  Grid is
     (rows/TR, W/TW) with sequential accumulation over the W axis into the
     revisited output block (identity-init at the first W step).
-  * ``ell_fold_batch_pallas``  — batched fold over the *native* [R, W, K]
-    gather layout: the edge tile is read ONCE and folded against all K
-    source columns resident in the same VMEM block, so kernel-level edge
-    traffic no longer scales with K.
-  * ``ell_gather_fold_pallas`` — 2-D-tiled (GridGraph-style) variant where
-    the source *interval* block x_blk is VMEM-resident and the gather runs
-    inside the kernel.
-  * ``ell_spmv_fused_pallas``  — the fused gather→fold kernel: the whole
-    [n, K] source matrix stays VMEM-resident across the grid and the gather
-    happens in-kernel, so the [R, W, K] gathered matrix is never
-    materialized in HBM.  Emits [R, K] per-ELL-row partials; the wrapped-row
-    segment-combine runs outside on the W×-smaller partials (in-kernel
-    scatter across row tiles is not expressible on TPU Pallas because
-    ``row_map`` segments span tiles).
+  * ``ell_fold_batch_pallas``  — K frontiers against one edge tile.  The
+    gathered sources arrive column-major, [K, R, W], so each column is a
+    plain [tr, tw] tile: the kernel loads the edge tile ONCE per block and
+    runs the single-column fold on every column of the block.  No edge tile
+    is ever broadcast to a new rank (Mosaic cannot lay that out), and each
+    column's reduction is the K=1 kernel's reduction, tile for tile.
 
 Edge values may arrive quantized (int8/float16, see
 ``repro.core.shards.quantize_edge_vals``); every kernel dequantizes them
@@ -30,7 +23,8 @@ in-VMEM from a (1, 2) float32 (scale, zero) qparams block, so HBM traffic
 for edge values is the *quantized* byte count.
 
 All kernels are validated in interpret mode against `ref.py` over
-shape/dtype/semiring sweeps (tests/test_kernels_spmv.py).
+shape/dtype/semiring sweeps (tests/test_kernels_spmv.py), and compiled for
+a described TPU v5e at real widths (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -46,10 +40,15 @@ from repro.core.shards import LANE, SUBLANE
 DEFAULT_TR = 256  # row-tile (multiple of 8 sublanes)
 DEFAULT_TW = 512  # width-tile (multiple of 128 lanes)
 
-# VMEM budget for the gathered-source tile of the batched kernels: the
-# [tr, tw, K] block is the largest resident array, so (tr, tw) shrink until
-# it fits (TPU cores have ~16 MB VMEM; 2 MB leaves room for edges + output).
+# VMEM budget for the gathered-source block of the batched kernel: the
+# [tk, tr, tw] block is the largest resident array, so (tr, tk) shrink until
+# it fits (TPU cores have ~16 MB VMEM; 2 MB leaves room for edges + output
+# under double buffering).
 TILE_BYTES_BUDGET = 2 << 20
+
+# Smallest row tile the batched kernel shrinks to: int8 edge tiles are laid
+# out in (32, 128) VMEM tiles, so 32 rows keeps every edge dtype aligned.
+MIN_BATCH_TR = 32
 
 # Edge-value dtypes that carry affine qparams (scale, zero).  bfloat16 and
 # other float dtypes pass through the semiring untouched.
@@ -68,10 +67,9 @@ def vmem_block_bytes(shape, itemsize: int = 4) -> int:
     """Actual VMEM footprint of a block of the given shape.
 
     VMEM lays blocks out in (8 sublane, 128 lane) tiles over the two minor
-    dims, so both are padded up: a [tr, tw, 1] source tile really occupies
-    tr * tw * 128 elements, not tr * tw.  Every byte budget in this module
-    (and ops.FUSED_X_BYTES_LIMIT) must be compared against this padded
-    size — the unpadded product under-counts K=1 blocks by 128x.
+    dims, so both are padded up: a [tr, 1] block really occupies tr * 128
+    elements, not tr.  Every byte budget in this module must be compared
+    against this padded size.
     """
     dims = list(shape)
     if len(dims) >= 1:
@@ -107,6 +105,7 @@ def _edge_tile(vals_ref, qp_ref):
 
 
 def _fold_tile(sem: Semiring, vals, xg, cols):
+    """[tr, tw] edges × [(tk,) tr, tw] gathered sources -> [(tk,) tr, 1] partials."""
     mask = cols >= 0
     contrib = sem.combine(vals, xg)
     contrib = jnp.where(mask, contrib, jnp.asarray(sem.identity, contrib.dtype))
@@ -117,33 +116,23 @@ def _fold_tile(sem: Semiring, vals, xg, cols):
     return jnp.min(contrib, axis=-1, keepdims=True)
 
 
-def _fold_tile_batch(sem: Semiring, vals, xg, cols):
-    """[tr, tw] edges × [tr, tw, K] gathered sources -> [tr, K] partials."""
-    mask = cols >= 0
-    contrib = sem.combine(vals[:, :, None], xg)
-    contrib = jnp.where(mask[:, :, None], contrib,
-                        jnp.asarray(sem.identity, contrib.dtype))
-    if sem.is_plus:
-        return jnp.sum(contrib, axis=1)
-    if sem.is_max:
-        return jnp.max(contrib, axis=1)
-    return jnp.min(contrib, axis=1)
+def _batch_tiles(R: int, W: int, K: int, itemsize: int = 4) -> tuple[int, int, int]:
+    """(tk, tr, tw) such that the [tk, tr, tw] source block fits the budget.
 
-
-def _batch_tiles(R: int, W: int, K: int, itemsize: int = 4) -> tuple[int, int]:
-    """(tr, tw) such that the [tr, tw, K] source tile fits the VMEM budget.
-
-    The budget is checked against the *padded* footprint
-    (``vmem_block_bytes``): K sits on the lane dim and pads to 128, so small
-    K shrinks (tr, tw) much harder than the raw element count suggests.
+    ``tw`` is the single-column kernel's width tile, never shrunk: every
+    column then reduces over exactly the lanes ``ell_fold_pallas`` would,
+    so a batched column equals its solo run bitwise.  Rows shrink first
+    (rows are independent), then the column block.
     """
-    tr, tw = min(DEFAULT_TR, R), min(DEFAULT_TW, W)
-    floor_w, floor_r = min(W, LANE), min(R, SUBLANE)
-    while vmem_block_bytes((tr, tw, K), itemsize) > TILE_BYTES_BUDGET and tw > floor_w:
-        tw = max(tw // 2, floor_w)
-    while vmem_block_bytes((tr, tw, K), itemsize) > TILE_BYTES_BUDGET and tr > floor_r:
+    tw = min(DEFAULT_TW, W)
+    tr = min(DEFAULT_TR, R)
+    tk = K
+    floor_r = min(R, MIN_BATCH_TR)
+    while vmem_block_bytes((tk, tr, tw), itemsize) > TILE_BYTES_BUDGET and tr > floor_r:
         tr = max(tr // 2, floor_r)
-    return tr, tw
+    while vmem_block_bytes((tk, tr, tw), itemsize) > TILE_BYTES_BUDGET and tk > 1:
+        tk = -(-tk // 2)
+    return tk, tr, tw
 
 
 def _split_qp(rest):
@@ -200,13 +189,13 @@ def ell_fold_pallas(xg: jnp.ndarray, vals: jnp.ndarray, cols: jnp.ndarray,
 
 def _ell_fold_batch_kernel(xg_ref, vals_ref, cols_ref, *rest, sem: Semiring):
     qp_ref, out_ref = _split_qp(rest)
-    w_step = pl.program_id(1)
-    # xg block is (tr, tw, K): the edge tile is loaded once and folded
-    # against ALL K resident source columns — kernel-level edge traffic is
-    # amortized across the batch (the old [K, R, W] layout revisited each
-    # edge tile K times and needed a transpose round-trip around the call).
-    partial = _fold_tile_batch(sem, _edge_tile(vals_ref, qp_ref),
-                               xg_ref[...], cols_ref[...])
+    w_step = pl.program_id(2)
+    # the edge tile is loaded once and broadcast along the LEADING (column)
+    # dim of the (tk, tr, tw) source block — the (sublane, lane) layout of
+    # every [tr, tw] slice is untouched, so each column folds exactly as in
+    # ell_fold_pallas
+    partial = _fold_tile(sem, _edge_tile(vals_ref, qp_ref)[None],
+                         xg_ref[...], cols_ref[...][None])
 
     @pl.when(w_step == 0)
     def _init():
@@ -217,147 +206,36 @@ def _ell_fold_batch_kernel(xg_ref, vals_ref, cols_ref, *rest, sem: Semiring):
         out_ref[...] = sem.reduce(out_ref[...], partial)
 
 
-@functools.partial(jax.jit, static_argnames=("semiring", "tr", "tw", "interpret"))
+@functools.partial(jax.jit, static_argnames=("semiring", "interpret"))
 def ell_fold_batch_pallas(xg: jnp.ndarray, vals: jnp.ndarray, cols: jnp.ndarray,
-                          semiring: str, tr: int | None = None,
-                          tw: int | None = None,
-                          interpret: bool = True, qparams=None) -> jnp.ndarray:
-    """Batched fold over the native gather layout: [R, W, K] -> [R, K].
+                          semiring: str, interpret: bool = True,
+                          qparams=None) -> jnp.ndarray:
+    """Batched fold: [K, R, W] gathered sources + [R, W] edges -> [R, K].
 
-    Grid is (rows/TR, W/TW) with the W axis innermost-sequential, exactly
-    like ``ell_fold_pallas``; K stays resident inside each block.  Tile
-    sizes shrink automatically so the [tr, tw, K] source tile fits VMEM.
+    Grid is (K/TK, rows/TR, W/TW) with the W axis innermost-sequential,
+    exactly like ``ell_fold_pallas``; tiles shrink (``_batch_tiles``) so the
+    [tk, tr, tw] source block fits VMEM.
     """
     sem = _as_semiring(semiring)
-    R, W, K = xg.shape
-    atr, atw = _batch_tiles(R, W, K, xg.dtype.itemsize)
-    tr = min(tr, R) if tr else atr
-    tw = min(tw, W) if tw else atw
-    grid = (pl.cdiv(R, tr), pl.cdiv(W, tw))
+    K, R, W = xg.shape
+    tk, tr, tw = _batch_tiles(R, W, K, xg.dtype.itemsize)
+    grid = (pl.cdiv(K, tk), pl.cdiv(R, tr), pl.cdiv(W, tw))
     quant = _is_quantized(vals)
     in_specs = [
-        pl.BlockSpec((tr, tw, K), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((tr, tw), lambda i, j: (i, j)),
-        pl.BlockSpec((tr, tw), lambda i, j: (i, j)),
+        pl.BlockSpec((tk, tr, tw), lambda c, i, j: (c, i, j)),
+        pl.BlockSpec((tr, tw), lambda c, i, j: (i, j)),
+        pl.BlockSpec((tr, tw), lambda c, i, j: (i, j)),
     ]
     args = [xg, vals, cols]
     if quant:
-        in_specs.append(pl.BlockSpec((1, 2), lambda i, j: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, 2), lambda c, i, j: (0, 0)))
         args.append(_qparams_2d(qparams))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_ell_fold_batch_kernel, sem=sem),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tr, K), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, K), xg.dtype),
+        out_specs=pl.BlockSpec((tk, tr, 1), lambda c, i, j: (c, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((K, R, 1), xg.dtype),
         interpret=interpret,
     )(*args)
-
-
-def _ell_gather_fold_kernel(x_ref, cols_ref, vals_ref, *rest, sem: Semiring):
-    qp_ref, out_ref = _split_qp(rest)
-    w_step = pl.program_id(1)
-    cols = cols_ref[...]
-    safe = jnp.where(cols >= 0, cols, 0)
-    # VMEM gather: the source interval block is fully resident in x_ref.
-    xg = jnp.take(x_ref[0], safe.reshape(-1), axis=0).reshape(cols.shape)
-    partial = _fold_tile(sem, _edge_tile(vals_ref, qp_ref), xg, cols)
-
-    @pl.when(w_step == 0)
-    def _init():
-        out_ref[...] = partial
-
-    @pl.when(w_step != 0)
-    def _acc():
-        out_ref[...] = sem.reduce(out_ref[...], partial)
-
-
-@functools.partial(jax.jit, static_argnames=("semiring", "tr", "tw", "interpret"))
-def ell_gather_fold_pallas(x_blk: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
-                           semiring: str, tr: int = DEFAULT_TR, tw: int = DEFAULT_TW,
-                           interpret: bool = True, qparams=None) -> jnp.ndarray:
-    """2-D-tiled SpMV: cols index the VMEM-resident source block x_blk [VB]."""
-    sem = _as_semiring(semiring)
-    R, W = cols.shape
-    VB = x_blk.shape[0]
-    tr = min(tr, R)
-    tw = min(tw, W)
-    grid = (pl.cdiv(R, tr), pl.cdiv(W, tw))
-    quant = _is_quantized(vals)
-    in_specs = [
-        pl.BlockSpec((1, VB), lambda i, j: (0, 0)),  # whole interval, revisited
-        pl.BlockSpec((tr, tw), lambda i, j: (i, j)),
-        pl.BlockSpec((tr, tw), lambda i, j: (i, j)),
-    ]
-    args = [x_blk[None, :], cols, vals]
-    if quant:
-        in_specs.append(pl.BlockSpec((1, 2), lambda i, j: (0, 0)))
-        args.append(_qparams_2d(qparams))
-    return pl.pallas_call(
-        functools.partial(_ell_gather_fold_kernel, sem=sem),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((tr, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, 1), x_blk.dtype),
-        interpret=interpret,
-    )(*args)
-
-
-def _ell_spmv_fused_kernel(x_ref, cols_ref, vals_ref, *rest, sem: Semiring):
-    qp_ref, out_ref = _split_qp(rest)
-    w_step = pl.program_id(1)
-    cols = cols_ref[...]
-    safe = jnp.where(cols >= 0, cols, 0)
-    k = x_ref.shape[1]
-    # In-kernel gather: x [n, K] is fully VMEM-resident across the grid, so
-    # the [R, W, K] gathered matrix never exists in HBM.
-    xg = jnp.take(x_ref[...], safe.reshape(-1), axis=0)
-    xg = xg.reshape(cols.shape + (k,))
-    partial = _fold_tile_batch(sem, _edge_tile(vals_ref, qp_ref), xg, cols)
-
-    @pl.when(w_step == 0)
-    def _init():
-        out_ref[...] = partial
-
-    @pl.when(w_step != 0)
-    def _acc():
-        out_ref[...] = sem.reduce(out_ref[...], partial)
-
-
-@functools.partial(jax.jit, static_argnames=("semiring", "tr", "tw", "interpret"))
-def ell_spmv_fused_pallas(x: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
-                          semiring: str, tr: int | None = None,
-                          tw: int | None = None,
-                          interpret: bool = True, qparams=None) -> jnp.ndarray:
-    """Fused gather→fold: [n, K] resident sources + [R, W] edges -> [R, K].
-
-    The caller gates this on the padded [n, K] footprint
-    (``vmem_block_bytes``) fitting a VMEM budget (ops.FUSED_X_BYTES_LIMIT);
-    the wrapped-row segment-combine runs outside the kernel on the
-    W×-smaller [R, K] partials.
-    """
-    sem = _as_semiring(semiring)
-    R, W = cols.shape
-    n, K = x.shape
-    atr, atw = _batch_tiles(R, W, K, x.dtype.itemsize)
-    tr = min(tr, R) if tr else atr
-    tw = min(tw, W) if tw else atw
-    grid = (pl.cdiv(R, tr), pl.cdiv(W, tw))
-    quant = _is_quantized(vals)
-    in_specs = [
-        pl.BlockSpec((n, K), lambda i, j: (0, 0)),  # whole frontier, revisited
-        pl.BlockSpec((tr, tw), lambda i, j: (i, j)),
-        pl.BlockSpec((tr, tw), lambda i, j: (i, j)),
-    ]
-    args = [x, cols, vals]
-    if quant:
-        in_specs.append(pl.BlockSpec((1, 2), lambda i, j: (0, 0)))
-        args.append(_qparams_2d(qparams))
-    return pl.pallas_call(
-        functools.partial(_ell_spmv_fused_kernel, sem=sem),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((tr, K), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, K), x.dtype),
-        interpret=interpret,
-    )(*args)
+    return out[:, :, 0].T

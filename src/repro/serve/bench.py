@@ -324,7 +324,10 @@ def main(argv=None) -> int:
                     help="exit 1 unless the controller converged cleanly")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
     from repro.session import GraphSession
+
+    enable_compile_cache()
 
     store = args.graph or prepare_store(args.scale, args.edge_factor)
 

@@ -90,7 +90,7 @@ def test_all_masked_rows_give_identity():
 
 
 # ---------------------------------------------------------------------------
-# fused gather→fold kernel + batched native layout + dispatch
+# batched fold kernel (column-major [K, R, W] gather layout) + dispatch
 # ---------------------------------------------------------------------------
 EXACT_SEMIS = ["min_plus", "max_src"]  # no float re-association: bitwise
 
@@ -103,39 +103,43 @@ def _make_batch(rng, n, R, W, K):
     return cols, vals, x, row_map
 
 
-@pytest.mark.parametrize("semiring", EXACT_SEMIS)
+def _gather_cols_major(x, cols):
+    """[n, K] sources gathered column-major: [K, R, W]."""
+    return np.ascontiguousarray(x.T[:, np.where(cols >= 0, cols, 0)])
+
+
+@pytest.mark.parametrize("semiring", SEMIS)
 @pytest.mark.parametrize("k", [1, 5])
-def test_fused_vs_unfused_bitwise(semiring, k):
-    """The fused in-kernel-gather path is bitwise-identical to the unfused
-    XLA-gather + fold kernel on exact (min/max) semirings."""
+def test_batch_columns_match_solo_fold_bitwise(semiring, k):
+    """Each column of the batched kernel is the single-column fold kernel's
+    result, bit for bit, on every semiring: both reduce the same [tr, tw]
+    tiles in the same order (run_batch columns equal solo runs)."""
     rng = np.random.default_rng(42 + k)
     n, R, W = 700, 64, 256
-    cols, vals, x, row_map = _make_batch(rng, n, R, W, k)
-    fused = spmv.ell_spmv_fused_pallas(
-        jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals), semiring,
-        interpret=True)
-    xg = x[np.where(cols >= 0, cols, 0)]
-    unfused = spmv.ell_fold_batch_pallas(
-        jnp.asarray(xg), jnp.asarray(vals), jnp.asarray(cols), semiring,
-        interpret=True)
-    assert np.array_equal(np.asarray(fused), np.asarray(unfused))
-    want = ref.ell_fold_batch_ref(jnp.asarray(xg), jnp.asarray(vals),
-                                  jnp.asarray(cols), semiring)
-    assert np.array_equal(np.asarray(fused), np.asarray(want))
+    cols, vals, x, _ = _make_batch(rng, n, R, W, k)
+    out = np.asarray(spmv.ell_fold_batch_pallas(
+        jnp.asarray(_gather_cols_major(x, cols)), jnp.asarray(vals),
+        jnp.asarray(cols), semiring, interpret=True))
+    assert out.shape == (R, k)
+    for c in range(k):
+        xg = x[np.where(cols >= 0, cols, 0), c]
+        solo = spmv.ell_fold_pallas(jnp.asarray(xg), jnp.asarray(vals),
+                                    jnp.asarray(cols), semiring,
+                                    interpret=True)
+        assert np.array_equal(out[:, c], np.asarray(solo)[:, 0])
 
 
 @pytest.mark.parametrize("semiring", SEMIS)
 def test_batch_native_layout_vs_ref(semiring):
-    """ell_fold_batch_pallas consumes [R, W, K] natively — no transpose
-    round-trip — and matches the oracle."""
+    """ell_fold_batch_pallas consumes the column-major [K, R, W] gather
+    layout natively and matches the oracle."""
     rng = np.random.default_rng(5)
     cols, vals, x, _ = _make_batch(rng, 400, 72, 384, 6)
-    xg = x[np.where(cols >= 0, cols, 0)]
-    out = spmv.ell_fold_batch_pallas(jnp.asarray(xg), jnp.asarray(vals),
-                                     jnp.asarray(cols), semiring,
-                                     interpret=True)
-    want = ref.ell_fold_batch_ref(jnp.asarray(xg), jnp.asarray(vals),
-                                  jnp.asarray(cols), semiring)
+    xg = jnp.asarray(_gather_cols_major(x, cols))
+    out = spmv.ell_fold_batch_pallas(xg, jnp.asarray(vals), jnp.asarray(cols),
+                                     semiring, interpret=True)
+    want = ref.ell_fold_batch_ref(xg, jnp.asarray(vals), jnp.asarray(cols),
+                                  semiring)
     assert out.shape == (72, 6)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6)
 
@@ -146,7 +150,7 @@ def _count_gathers_outside_pallas(jaxpr) -> int:
     count = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            continue  # in-kernel gathers read from VMEM, not HBM
+            continue
         if eqn.primitive.name == "gather":
             count += 1
         for v in eqn.params.values():
@@ -156,45 +160,30 @@ def _count_gathers_outside_pallas(jaxpr) -> int:
     return count
 
 
-def test_fused_path_has_no_hbm_gather():
-    """The fused kernel never materializes a gathered copy: zero XLA gathers
-    in the jaxpr.  The unfused Pallas path gathers exactly once (never the
-    double gather the pre-fix layout churn risked)."""
+def test_batch_pallas_path_gathers_once():
+    """The Pallas batch path gathers the [K, R, W] sources in ONE XLA
+    gather (no per-column gathers, no second gather for the layout)."""
     rng = np.random.default_rng(0)
     n, R, W, k = 600, 16, 128, 3
     cols, vals, x, row_map = _make_batch(rng, n, R, W, k)
     args = (jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
             jnp.asarray(row_map))
-    fused_jaxpr = jax.make_jaxpr(
+    jaxpr = jax.make_jaxpr(
         lambda *a: ell_spmv_batch(*a, R, "min_plus", use_pallas=True))(*args)
-    assert _count_gathers_outside_pallas(fused_jaxpr.jaxpr) == 0
-
-    # different shape (fresh trace) + a limit of 0 forces the unfused path
-    cols2, vals2, x2, row_map2 = _make_batch(rng, n, R, W * 2, k)
-    args2 = (jnp.asarray(x2), jnp.asarray(cols2), jnp.asarray(vals2),
-             jnp.asarray(row_map2))
-    old = ops.FUSED_X_BYTES_LIMIT
-    ops.FUSED_X_BYTES_LIMIT = 0
-    try:
-        fold_jaxpr = jax.make_jaxpr(
-            lambda *a: ell_spmv_batch(*a, R, "min_plus", use_pallas=True))(*args2)
-    finally:
-        ops.FUSED_X_BYTES_LIMIT = old
-    assert _count_gathers_outside_pallas(fold_jaxpr.jaxpr) == 1
+    assert _count_gathers_outside_pallas(jaxpr.jaxpr) == 1
 
 
 def test_dispatch_table_cpu():
     """docs/ARCHITECTURE.md dispatch table, executable form (CPU backend)."""
-    assert describe_dispatch(False, n=1000, k=1) == "jnp"
-    assert describe_dispatch(False, n=1000, k=16) == "jnp"
+    assert describe_dispatch(False, k=1) == "jnp"
+    assert describe_dispatch(False, k=16) == "jnp"
     # auto on an interpreting backend: single-column keeps the cheap Pallas
     # referee path, batched falls back to jnp
-    assert describe_dispatch("auto", n=1000, k=1) == "pallas:interpret:gather+fold"
-    assert describe_dispatch("auto", n=1000, k=16) == "jnp"
-    # forced Pallas: fused when the frontier fits VMEM, fold otherwise
-    assert describe_dispatch(True, n=1000, k=16) == "pallas:interpret:fused"
-    big = ops.FUSED_X_BYTES_LIMIT  # bytes -> elements: guaranteed too big
-    assert describe_dispatch(True, n=big, k=16) == "pallas:interpret:gather+fold"
+    assert describe_dispatch("auto", k=1) == "pallas:interpret:gather+fold"
+    assert describe_dispatch("auto", k=16) == "jnp"
+    # forced Pallas: the fold kernels for every K
+    assert describe_dispatch(True, k=1) == "pallas:interpret:gather+fold"
+    assert describe_dispatch(True, k=16) == "pallas:interpret:gather+fold"
 
 
 def test_resolve_no_dead_interpret_flag():
@@ -207,16 +196,23 @@ def test_resolve_no_dead_interpret_flag():
 
 
 def test_compiled_dispatch_is_tpu_only(monkeypatch):
-    """GPU backends run grid programs in parallel, so the kernels' sequential
-    W-axis accumulation must never compile there: 'auto' demotes to the
+    """TPU compiles the fold kernels for every K under 'auto'.  GPU backends
+    run grid programs in parallel, so the kernels' sequential W-axis
+    accumulation must never compile there: 'auto' demotes to the
     fully-XLA-compiled jnp path, forced True keeps the interpret referee."""
     assert ops._COMPILED_BACKENDS == ("tpu",)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._resolve("auto") == (True, False)
+    for k in (1, 16):
+        assert describe_dispatch("auto", k=k) == "pallas:compiled:gather+fold"
+        assert describe_dispatch(True, k=k) == "pallas:compiled:gather+fold"
+        assert describe_dispatch(False, k=k) == "jnp"
     for backend in ("gpu", "cuda", "rocm"):
         monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
         assert ops._resolve("auto") == (True, True)
-        assert describe_dispatch("auto", n=1000, k=1) == "jnp"
-        assert describe_dispatch("auto", n=1000, k=16) == "jnp"
-        assert describe_dispatch(True, n=1000, k=16) == "pallas:interpret:fused"
+        assert describe_dispatch("auto", k=1) == "jnp"
+        assert describe_dispatch("auto", k=16) == "jnp"
+        assert describe_dispatch(True, k=16) == "pallas:interpret:gather+fold"
 
 
 def test_vmem_block_bytes_padding():
@@ -229,31 +225,30 @@ def test_vmem_block_bytes_padding():
     assert spmv.vmem_block_bytes((256, 8, 128), 4) == 256 * 8 * 128 * 4
 
 
-def test_fused_gate_uses_padded_bytes():
-    """The fused K=1 gate must admit only frontiers whose PADDED footprint
-    fits — n rows cost n*512 bytes in f32, not n*4."""
-    limit = ops.FUSED_X_BYTES_LIMIT
-    n_fits = limit // (128 * 4)  # padded bytes land exactly on the limit
-    assert ops._fused_fits(n_fits, 1, 4)
-    assert not ops._fused_fits(n_fits + 8, 1, 4)
-    # the old unpadded model would have admitted that frontier easily
-    assert (n_fits + 8) * 1 * 4 < limit
+def test_batch_tiles_keep_solo_width():
+    """The batched kernel never shrinks the width tile: each column then
+    reduces over exactly the lanes ell_fold_pallas reduces over."""
+    for (R, W, K) in [(512, 1024, 1), (80_000, 512, 16), (80_000, 128, 256),
+                      (8, 128, 3)]:
+        _tk, _tr, tw = spmv._batch_tiles(R, W, K, 4)
+        assert tw == min(spmv.DEFAULT_TW, W)
 
 
 def test_batch_tiles_respect_padded_budget():
-    """Auto-shrunk [tr, tw, K] tiles fit TILE_BYTES_BUDGET under the padded
-    model (or sit at the (SUBLANE, LANE) floor, the smallest legal tile)."""
-    for (R, W, K) in [(512, 1024, 1), (512, 1024, 16), (64, 256, 4)]:
-        tr, tw = spmv._batch_tiles(R, W, K, 4)
-        at_floor = tr <= min(R, spmv.SUBLANE) and tw <= min(W, spmv.LANE)
-        assert (spmv.vmem_block_bytes((tr, tw, K), 4)
+    """Auto-shrunk [tk, tr, tw] tiles fit TILE_BYTES_BUDGET under the padded
+    model (or sit at the floor: one column of MIN_BATCH_TR rows)."""
+    for (R, W, K) in [(512, 1024, 1), (512, 1024, 16), (64, 256, 4),
+                      (80_000, 512, 256)]:
+        tk, tr, tw = spmv._batch_tiles(R, W, K, 4)
+        at_floor = tk == 1 and tr <= min(R, spmv.MIN_BATCH_TR)
+        assert (spmv.vmem_block_bytes((tk, tr, tw), 4)
                 <= spmv.TILE_BYTES_BUDGET) or at_floor
 
 
 @pytest.mark.parametrize("semiring", EXACT_SEMIS)
 def test_ops_batch_paths_agree_bitwise(semiring):
-    """Public ell_spmv_batch: forced-Pallas (fused), forced-jnp, and auto all
-    agree bitwise on exact semirings."""
+    """Public ell_spmv_batch: forced-Pallas, forced-jnp, and auto all agree
+    bitwise on exact semirings."""
     rng = np.random.default_rng(17)
     cols, vals, x, row_map = _make_batch(rng, 500, 32, 128, 4)
     args = (jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
@@ -262,3 +257,14 @@ def test_ops_batch_paths_agree_bitwise(semiring):
             for up in (True, False, "auto")]
     assert np.array_equal(outs[0], outs[1])
     assert np.array_equal(outs[0], outs[2])
+
+
+def test_segment_combine_batch_drops_out_of_range_ids():
+    """Flattened batched combine: an id at num_segments (the sharded
+    engine's padded rows) is dropped, never spilled into the next column."""
+    partials = jnp.asarray(np.arange(12, dtype=np.float32).reshape(4, 3))
+    row_map = jnp.asarray(np.array([0, 1, 1, 2], np.int32))
+    out = np.asarray(ref.segment_combine_batch(partials, row_map, 2,
+                                               "plus_times"))
+    want = np.array([[0, 1, 2], [3 + 6, 4 + 7, 5 + 8]], np.float32)
+    assert np.array_equal(out, want)

@@ -1,12 +1,12 @@
 """Quantized edge values (int8/fp16): helpers, dequant-in-kernel, codecs,
-engine end-to-end, and the GRAPHMP_DEVICES=2 fused-kernel leg.
+engine end-to-end, and the GRAPHMP_DEVICES=2 Pallas leg.
 
 Tolerance contract (docs/ARCHITECTURE.md "Kernels"):
   * vs the fp32 oracle on the TRUE values — bounded error: per-edge
     |v - v_hat| <= scale/2 for int8 (affine, range widened to include 0)
     and <= 2^-11 |v| for fp16; min/max semirings propagate the per-edge
     bound unamplified.
-  * across dispatch paths (pallas fused / pallas fold / jnp fallback) —
+  * across dispatch paths (pallas fold / jnp fallback) —
     BITWISE on exact (min/max) semirings: every path applies the identical
     (q - zero) * scale arithmetic, so the referee property survives
     quantization.
@@ -103,7 +103,7 @@ def _problem(rng, n=700, R=64, W=256, K=4):
 @pytest.mark.parametrize("semiring", EXACT_SEMIS)
 @pytest.mark.parametrize("dtype", QDTYPES)
 def test_quantized_paths_bitwise_identical(semiring, dtype):
-    """All three dispatch paths (forced-Pallas fused, forced-jnp, auto)
+    """All three dispatch paths (forced-Pallas, forced-jnp, auto)
     produce bit-identical results on quantized values — the referee
     property the engine's correctness story leans on."""
     rng = np.random.default_rng(3)
@@ -180,23 +180,26 @@ def test_quantized_tolerance_vs_fp32_oracle(dtype):
 
 
 @pytest.mark.parametrize("dtype", QDTYPES)
-def test_fused_kernel_dequantizes(dtype):
-    """The fused in-kernel-gather path dequantizes identically too."""
+def test_batch_kernel_dequantizes_like_solo(dtype):
+    """The batched fold kernel dequantizes in-VMEM exactly as the
+    single-column kernel does: every column is bitwise its solo fold."""
     rng = np.random.default_rng(5)
     cols, vals, x, _ = _problem(rng)
     q, scale, zero = quantize_edge_vals(vals, dtype)
     qp = jnp.asarray([scale, zero], jnp.float32)
     vdq = jnp.asarray(dequantize_edge_vals(q, scale, zero))
-    out = spmv.ell_spmv_fused_pallas(jnp.asarray(x), jnp.asarray(cols),
-                                     jnp.asarray(q), "min_plus",
-                                     interpret=True, qparams=qp)
-    xg = jnp.asarray(x)[np.where(cols >= 0, cols, 0)]
-    unfused = spmv.ell_fold_batch_pallas(xg, jnp.asarray(q), jnp.asarray(cols),
-                                         "min_plus", interpret=True,
-                                         qparams=qp)
-    assert np.array_equal(np.asarray(out), np.asarray(unfused))
-    want = ref.ell_fold_batch_ref(xg, vdq, jnp.asarray(cols), "min_plus")
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=3e-7)
+    safe = np.where(cols >= 0, cols, 0)
+    out = np.asarray(spmv.ell_fold_batch_pallas(
+        jnp.asarray(x.T[:, safe]), jnp.asarray(q), jnp.asarray(cols),
+        "min_plus", interpret=True, qparams=qp))
+    for k in range(x.shape[1]):
+        solo = spmv.ell_fold_pallas(jnp.asarray(x[safe, k]), jnp.asarray(q),
+                                    jnp.asarray(cols), "min_plus",
+                                    interpret=True, qparams=qp)
+        assert np.array_equal(out[:, k], np.asarray(solo)[:, 0])
+    want = ref.ell_fold_batch_ref(jnp.asarray(x.T[:, safe]), vdq,
+                                  jnp.asarray(cols), "min_plus")
+    np.testing.assert_allclose(out, np.asarray(want), rtol=3e-7)
 
 
 def test_bfloat16_vals_not_dequantized():
@@ -289,7 +292,7 @@ def test_env_knob_and_validation(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", QDTYPES)
 def test_session_quantized_pallas_vs_jnp_bitwise(tmp_path, dtype):
-    """SSSP over a quantized store: forced-Pallas (fused, dequant-in-kernel)
+    """SSSP over a quantized store: forced-Pallas (dequant-in-kernel)
     and forced-jnp (host dequant formula) agree bitwise — the referee
     property the CI kernels job leans on."""
     from repro.core.engine import EngineConfig
@@ -340,10 +343,10 @@ def test_delta_mutation_keeps_quantized_dtype(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# GRAPHMP_DEVICES=2 leg: fused kernel under the sharded engine
+# GRAPHMP_DEVICES=2 leg: Pallas kernels under the sharded engine
 # ---------------------------------------------------------------------------
 def test_sharded_engine_fused_bitwise_two_devices(tmp_path):
-    """ShardedVSWEngine with GRAPHMP_USE_PALLAS=1 (fused kernels) over a
+    """ShardedVSWEngine with GRAPHMP_USE_PALLAS=1 (Pallas kernels) over a
     quantized store is bitwise-identical to the single-device engine."""
     code = textwrap.dedent("""
         import numpy as np

@@ -1,0 +1,98 @@
+"""Compile the SpMV kernels for a described TPU v5e at real widths.
+
+Nothing runs: XLA:TPU and Mosaic compile each kernel for ``v5e:2x2``'s first
+chip from shapes alone, so a kernel the TPU compiler refuses (a layout it
+cannot lower, a block over the VMEM limit) fails here, on a CPU machine,
+instead of on the chip.  Covers every kernel ``ops._pick_path`` can choose
+on TPU: the K=1 fold and the K=16 batched fold in f32 and int8, and the
+dispatching ``ell_spmv`` / ``ell_spmv_batch`` over an n = 2^22 frontier.
+
+The topology is described inside a module fixture — never at import — so
+every pytest-xdist worker collects the same tests and only the worker that
+runs this file loads the TPU compiler library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.spmv import ops, spmv
+
+N = 1 << 22      # frontier length: a scale-22 graph
+R = 32768        # ELL rows of one shard at 2^20 edges per shard
+K = 16           # run_batch / GraphService micro-batch width
+WIDTHS = (128, 512)  # the lane-width floor and the default ELL width cap
+EDGE_DTYPES = {"f32": jnp.float32, "int8": jnp.int8}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — any failure means "no TPU compiler"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the Pallas kernel is in
+    return compiled
+
+
+@pytest.mark.parametrize("edge_dtype", EDGE_DTYPES)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_fold_kernel_compiles(one_chip, width, edge_dtype):
+    def fold(xg, vals, cols, qp):
+        return spmv.ell_fold_pallas(xg, vals, cols, "min_plus",
+                                    interpret=False, qparams=qp)
+
+    _compile(one_chip, fold, ((R, width), jnp.float32),
+             ((R, width), EDGE_DTYPES[edge_dtype]), ((R, width), jnp.int32),
+             ((2,), jnp.float32))
+
+
+@pytest.mark.parametrize("semiring", ["min_plus", "plus_src"])
+@pytest.mark.parametrize("edge_dtype", EDGE_DTYPES)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_batch_fold_kernel_compiles(one_chip, width, edge_dtype, semiring):
+    def fold(xg, vals, cols, qp):
+        return spmv.ell_fold_batch_pallas(xg, vals, cols, semiring,
+                                          interpret=False, qparams=qp)
+
+    _compile(one_chip, fold, ((K, R, width), jnp.float32),
+             ((R, width), EDGE_DTYPES[edge_dtype]), ((R, width), jnp.int32),
+             ((2,), jnp.float32))
+
+
+@pytest.mark.parametrize("k", [1, K])
+@pytest.mark.parametrize("width", WIDTHS)
+def test_dispatched_spmv_compiles(one_chip, monkeypatch, width, k):
+    """The public ops as the engine calls them under use_pallas="auto",
+    steered onto their TPU branch: XLA gather + segment combine + kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.describe_dispatch("auto", k=k) == "pallas:compiled:gather+fold"
+    x_shape = (N,) if k == 1 else (N, k)
+    op = ops.ell_spmv if k == 1 else ops.ell_spmv_batch
+
+    def spmv_step(x, cols, vals, row_map):
+        return op(x, cols, vals, row_map, R, "min_plus")
+
+    compiled = _compile(one_chip, spmv_step, (x_shape, jnp.float32),
+                        ((R, width), jnp.int32), ((R, width), jnp.float32),
+                        ((R,), jnp.int32))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 16 << 30  # fits one v5e's HBM
