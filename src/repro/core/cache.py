@@ -54,7 +54,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 import warnings
 import zlib
 from collections import OrderedDict
@@ -65,6 +64,7 @@ except ImportError:  # optional: compressed tiers fall back to stdlib zlib
     zstandard = None
 
 from repro.core.shards import ELLShard
+from repro.core.spans import Counters, span
 from repro.graph.source import ShardSource, pack_shard_npz, unpack_shard_npz
 
 GAMMA = {0: 1.0, 1: 1.0, 2: 2.0, 3: 4.0, 4: 5.0}
@@ -97,7 +97,7 @@ def auto_select_mode(graph_bytes: int, cache_budget_bytes: int) -> int:
 
 
 @dataclasses.dataclass
-class CacheStats:
+class CacheStats(Counters):
     """Lifetime counters; mutate through ``bump`` (atomic under a lock).
 
     ``hits``/``misses``/``evictions`` keep their historic meaning (an
@@ -123,14 +123,6 @@ class CacheStats:
     demotions: int = 0
     decode_seconds_saved: float = 0.0
     stale_drops: int = 0  # entries dropped because their shard's epoch moved
-
-    def __post_init__(self):
-        self._lock = threading.Lock()
-
-    def bump(self, **deltas) -> None:
-        with self._lock:
-            for field, delta in deltas.items():
-                setattr(self, field, getattr(self, field) + delta)
 
     @property
     def hit_ratio(self) -> float:
@@ -272,10 +264,10 @@ class CompressedShardCache:
     # -- adaptive internals (all callers hold self._lock) ---------------
     def _demote(self, shard_id: int, shard: ELLShard) -> None:
         """Hot LRU leaves the hot tier: re-compressed into the cold tier."""
-        t = time.perf_counter()
-        blob = self._compress(_pack(shard))
-        self.stats.bump(compress_seconds=time.perf_counter() - t,
-                        demotions=1)
+        with span("graphmp.compress", self.stats, "compress_seconds",
+                  shard=shard_id):
+            blob = self._compress(_pack(shard))
+        self.stats.bump(demotions=1)
         self._cold[shard_id] = blob  # most-recently-used end of the cold LRU
         self._cold_bytes += len(blob)
 
@@ -317,11 +309,11 @@ class CompressedShardCache:
         if shard_id in self._cold:
             blob = self._cold.pop(shard_id)
             self._freq[shard_id] = self._freq.get(shard_id, 0) + 1
-            t = time.perf_counter()
-            shard = _unpack(shard_id, self._decompress(blob))
-            dt = time.perf_counter() - t
-            self._decode_cost[shard_id] = dt
-            self.stats.bump(hits=1, cold_hits=1, decompress_seconds=dt)
+            with span("graphmp.decode", self.stats, "decompress_seconds",
+                      shard=shard_id) as decode:
+                shard = _unpack(shard_id, self._decompress(blob))
+            self._decode_cost[shard_id] = decode.seconds
+            self.stats.bump(hits=1, cold_hits=1)
             if self._should_promote(shard_id, shard):
                 self._cold_bytes -= len(blob)
                 self._hot[shard_id] = shard
@@ -335,11 +327,12 @@ class CompressedShardCache:
         self.stats.bump(misses=1,
                         disk_bytes=self.store.shard_nbytes(shard_id))
         self._freq[shard_id] = self._freq.get(shard_id, 0) + 1
-        blob = self.store.read_shard_bytes(shard_id)
+        with span("graphmp.read", shard=shard_id):
+            blob = self.store.read_shard_bytes(shard_id)
         shard = _unpack(shard_id, blob)
-        t = time.perf_counter()
-        centry = self._compress(blob)
-        self.stats.bump(compress_seconds=time.perf_counter() - t)
+        with span("graphmp.compress", self.stats, "compress_seconds",
+                  shard=shard_id):
+            centry = self._compress(blob)
         if len(centry) <= self.budget:
             self._cold[shard_id] = centry
             self._cold_bytes += len(centry)
@@ -405,15 +398,16 @@ class CompressedShardCache:
             if self.mode == 0:
                 self.stats.bump(misses=1,
                                 disk_bytes=self.store.shard_nbytes(shard_id))
-                return self.store.read_shard(shard_id)
+                with span("graphmp.read", shard=shard_id):
+                    return self.store.read_shard(shard_id)
             if shard_id in self._lru:
                 entry = self._lru.pop(shard_id)
                 self._lru[shard_id] = entry  # LRU bump
                 if isinstance(entry, bytes):
-                    t = time.perf_counter()
-                    blob = self._decompress(entry)
-                    self.stats.bump(hits=1, cold_hits=1,
-                                    decompress_seconds=time.perf_counter() - t)
+                    with span("graphmp.decode", self.stats,
+                              "decompress_seconds", shard=shard_id):
+                        blob = self._decompress(entry)
+                    self.stats.bump(hits=1, cold_hits=1)
                     return _unpack(shard_id, blob)
                 self.stats.bump(hits=1, hot_hits=1)
                 return entry
@@ -421,16 +415,18 @@ class CompressedShardCache:
             self.stats.bump(misses=1,
                             disk_bytes=self.store.shard_nbytes(shard_id))
             if self.mode == 1:
-                shard = self.store.read_shard(shard_id)
+                with span("graphmp.read", shard=shard_id):
+                    shard = self.store.read_shard(shard_id)
                 entry: bytes | ELLShard = shard
             else:
                 # compress the canonical blob straight off the backend — no
                 # decode->re-encode round trip on the miss path
-                blob = self.store.read_shard_bytes(shard_id)
+                with span("graphmp.read", shard=shard_id):
+                    blob = self.store.read_shard_bytes(shard_id)
                 shard = _unpack(shard_id, blob)
-                t = time.perf_counter()
-                entry = self._compress(blob)
-                self.stats.bump(compress_seconds=time.perf_counter() - t)
+                with span("graphmp.compress", self.stats, "compress_seconds",
+                          shard=shard_id):
+                    entry = self._compress(blob)
             need = self._entry_nbytes(entry)
             if need <= self.budget:
                 self._evict_until(need)

@@ -58,6 +58,7 @@ from repro.core.engine import EngineConfig, VSWEngine
 from repro.core.pipeline import ShardPipeline
 from repro.core.shards import (LANE, SUBLANE, ELLShard, build_csr_shards,
                                csr_to_ell, dequantize_edge_vals)
+from repro.core.spans import span
 from repro.dist.context import make_data_mesh
 from repro.kernels.spmv.ops import ell_spmv, ell_spmv_batch
 
@@ -342,43 +343,55 @@ class ShardedVSWEngine(VSWEngine):
             qp[d] = q
             start[d] = shard.start_vertex
             nrows[d] = nr
+        arrays = (cols, vals, rmap, qp, start, nrows)
+        # each device receives one slice of every array: charge its bytes
+        # to the device's lane
+        per_device = sum(a.nbytes for a in arrays) // D
+        for lane in self._lanes:
+            lane.stats.bump(h2d_bytes=per_device)
         sharding = NamedSharding(self._mesh, P(self._axis))
-        return tuple(jax.device_put(a, sharding)
-                     for a in (cols, vals, rmap, qp, start, nrows))
+        return tuple(jax.device_put(a, sharding) for a in arrays)
 
-    def _sweep(self, x, src, aux_dev, it_dev, schedule, epoch_check):
+    def _sweep(self, x, src, aux_dev, it_dev, schedule, epoch_check,
+               it: int = 0):
         D = self._num_devices
         scheds = [[p for p in schedule if self._owner[p] == d]
                   for d in range(D)]
         waves = max(len(s) for s in scheds)
         dst = self._dst_init(src)
-        streams = [self._lanes[d].stream(scheds[d], check=epoch_check)
+        streams = [self._lanes[d].stream(scheds[d], check=epoch_check,
+                                         sweep=it)
                    for d in range(D)]
         try:
             for w in range(waves):
                 entries = [next(streams[d]) if w < len(scheds[d]) else None
                            for d in range(D)]
-                tail = self._assemble_wave(entries)
-                if self.batched:
-                    dst = self._wave_step(dst, x, src, aux_dev, it_dev, *tail)
-                else:
-                    dst = self._wave_step(dst, x, src, *tail)
+                with span("graphmp.step", sweep=it, wave=w):
+                    tail = self._assemble_wave(entries)
+                    if self.batched:
+                        dst = self._wave_step(dst, x, src, aux_dev, it_dev,
+                                              *tail)
+                    else:
+                        dst = self._wave_step(dst, x, src, *tail)
         finally:
             for s in streams:
                 s.close()  # run pipeline cleanup (reap prefetch workers)
-        new_src, changed_count = self._merge_step(dst, src)
-        if int(changed_count) == 0:
-            # the psum'd count short-circuits the full mask pull
-            shape = ((self.n, src.shape[1]) if self.batched else (self.n,))
-            changed = np.zeros(shape, dtype=bool)
-        else:
-            changed = np.asarray(self._changed_fn(new_src, src))
+        with span("graphmp.changed", sweep=it):
+            new_src, changed_count = self._merge_step(dst, src)
+            if int(changed_count) == 0:
+                # the psum'd count short-circuits the full mask pull
+                shape = ((self.n, src.shape[1]) if self.batched
+                         else (self.n,))
+                changed = np.zeros(shape, dtype=bool)
+            else:
+                changed = np.asarray(self._changed_fn(new_src, src))
         return new_src, changed
 
     def _io_marks(self):
         return ([(c.stats.disk_bytes, c.stats.hits, c.stats.misses,
                   c.stats.decode_seconds_saved) for c in self.cache.parts],
-                [(l.stats.stall_seconds, l.stats.fetch_seconds)
+                [(l.stats.stall_seconds, l.stats.fetch_seconds,
+                  l.stats.stage_seconds, l.stats.h2d_bytes)
                  for l in self._lanes])
 
     def _io_stats(self, marks) -> dict:
@@ -391,10 +404,10 @@ class ShardedVSWEngine(VSWEngine):
             d_saved.append(s.decode_seconds_saved - saved0)
             hits += s.hits - hits0
             total += (s.hits - hits0) + (s.misses - misses0)
-        d_stall = [l.stats.stall_seconds - s0
-                   for l, (s0, _f0) in zip(self._lanes, lane_marks)]
-        d_fetch = [l.stats.fetch_seconds - f0
-                   for l, (_s0, f0) in zip(self._lanes, lane_marks)]
+        d_stall = [l.stats.stall_seconds - m[0]
+                   for l, m in zip(self._lanes, lane_marks)]
+        d_fetch = [l.stats.fetch_seconds - m[1]
+                   for l, m in zip(self._lanes, lane_marks)]
         return dict(
             disk_bytes=sum(d_disk),
             cache_hit_ratio=hits / total if total else 0.0,
@@ -403,6 +416,10 @@ class ShardedVSWEngine(VSWEngine):
             # per worker and also sums
             stall_seconds=sum(d_stall),
             fetch_seconds=sum(d_fetch),
+            stage_seconds=sum(l.stats.stage_seconds - m[2]
+                              for l, m in zip(self._lanes, lane_marks)),
+            h2d_bytes=sum(l.stats.h2d_bytes - m[3]
+                          for l, m in zip(self._lanes, lane_marks)),
             decode_seconds_saved=sum(d_saved),
             device_disk_bytes=tuple(d_disk),
             device_stall_seconds=tuple(d_stall),

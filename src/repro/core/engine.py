@@ -37,7 +37,6 @@ import dataclasses
 import json
 import os
 import threading
-import time
 import warnings
 from pathlib import Path
 from typing import Iterator
@@ -50,10 +49,16 @@ from repro.core.apps import BatchedVertexProgram, VertexProgram
 from repro.core.cache import CompressedShardCache
 from repro.core.pipeline import ShardPipeline
 from repro.core.shards import ELLShard
+from repro.core.spans import span
 from repro.graph.source import ConcurrentMutationError, ShardSource
 from repro.kernels.spmv.ops import ell_spmv, ell_spmv_batch
 
 _VALID_CACHE_MODES = (0, 1, 2, 3, 4)
+
+
+def _device_nbytes(staged) -> int:
+    """Bytes of the device arrays one ``_stage`` call returned."""
+    return sum(a.nbytes for a in staged)
 
 
 def _store_epoch(store) -> int:
@@ -226,6 +231,8 @@ class IterationStats:
     edges_processed: int = 0    # sum of nnz over the shards actually run
     stall_seconds: float = 0.0  # time the compute loop waited on shard I/O
     fetch_seconds: float = 0.0  # fetch+stage time (overlapped when prefetching)
+    stage_seconds: float = 0.0  # of which host->device staging
+    h2d_bytes: int = 0          # bytes staged to the device
     decode_seconds_saved: float = 0.0  # decompression cost hot-tier hits skipped
     # multi-device runs only (empty tuples otherwise): per-device splits of
     # the aggregates above — one entry per device, summing (disk/fetch) or
@@ -245,8 +252,8 @@ class RunResult:
     ran, ``converged`` whether the frontier emptied before ``max_iters``,
     and ``history`` one ``IterationStats`` per iteration (per-iteration
     seconds, active ratio, shards processed/skipped, disk bytes, cache hit
-    ratio, stall/fetch seconds).  ``total_seconds``/``edges_per_second``
-    aggregate it.
+    ratio, stall/fetch/stage seconds, bytes staged to the device).
+    ``total_seconds``/``edges_per_second`` aggregate it.
     """
 
     values: np.ndarray
@@ -557,7 +564,8 @@ class VSWEngine:
         """Build the shard stream consumed by ``_sweep``."""
         return ShardPipeline(
             self._get_shard, depth=self.config.prefetch_depth,
-            stage=self._stage, nbytes=ELLShard.decoded_nbytes)
+            stage=self._stage, nbytes=ELLShard.decoded_nbytes,
+            h2d=_device_nbytes)
 
     def _get_shard(self, p: int) -> ELLShard:
         if p in self._preloaded:
@@ -632,11 +640,12 @@ class VSWEngine:
         against (opaque to iter_run; paired with ``_io_stats``)."""
         cs, ps = self.cache.stats, self._pipeline.stats
         return (cs.disk_bytes, cs.hits, cs.misses, cs.decode_seconds_saved,
-                ps.stall_seconds, ps.fetch_seconds)
+                ps.stall_seconds, ps.fetch_seconds, ps.stage_seconds,
+                ps.h2d_bytes)
 
     def _io_stats(self, marks) -> dict:
         """IterationStats I/O fields as deltas against ``marks``."""
-        disk0, hits0, misses0, saved0, stall0, fetch0 = marks
+        disk0, hits0, misses0, saved0, stall0, fetch0, stage0, h2d0 = marks
         cs, ps = self.cache.stats, self._pipeline.stats
         d_hits = cs.hits - hits0
         d_total = d_hits + cs.misses - misses0
@@ -645,25 +654,33 @@ class VSWEngine:
             cache_hit_ratio=d_hits / d_total if d_total else 0.0,
             stall_seconds=ps.stall_seconds - stall0,
             fetch_seconds=ps.fetch_seconds - fetch0,
+            stage_seconds=ps.stage_seconds - stage0,
+            h2d_bytes=ps.h2d_bytes - h2d0,
             decode_seconds_saved=cs.decode_seconds_saved - saved0,
         )
 
-    def _sweep(self, x, src, aux_dev, it_dev, schedule, epoch_check):
-        """One edge sweep: stream the scheduled shards, fold each into the
-        destination array.  Returns ``(new values [n_pad(, K)],
-        changed mask [n(, K)] as a numpy bool array)``."""
+    def _sweep(self, x, src, aux_dev, it_dev, schedule, epoch_check,
+               it: int = 0):
+        """One edge sweep (iteration ``it``): stream the scheduled shards,
+        fold each into the destination array.  Returns ``(new values
+        [n_pad(, K)], changed mask [n(, K)] as a numpy bool array)``."""
         dst = src + 0.0  # materialize a copy: the shard step donates its dst
-        for _p, shard, dev in self._pipeline.stream(schedule,
-                                                    check=epoch_check):
+        for p, shard, dev in self._pipeline.stream(schedule,
+                                                   check=epoch_check,
+                                                   sweep=it):
             cols_dev, vals_dev, row_map_dev, qp_dev = dev
             tail = (cols_dev, vals_dev, row_map_dev, qp_dev,
                     shard.start_vertex,
                     shard.end_vertex - shard.start_vertex)
-            if self.batched:
-                dst = self._shard_step(dst, x, src, aux_dev, it_dev, *tail)
-            else:
-                dst = self._shard_step(dst, x, src, *tail)
-        return dst, np.asarray(self._changed_fn(dst, src))
+            with span("graphmp.step", sweep=it, shard=p):
+                if self.batched:
+                    dst = self._shard_step(dst, x, src, aux_dev, it_dev,
+                                           *tail)
+                else:
+                    dst = self._shard_step(dst, x, src, *tail)
+        with span("graphmp.changed", sweep=it):
+            changed = np.asarray(self._changed_fn(dst, src))
+        return dst, changed
 
     # ------------------------------------------------------------------
     def iter_run(
@@ -776,33 +793,37 @@ class VSWEngine:
 
         last_changed = active_mask
         for it in range(start_iter, max_iters):
-            t0 = time.time()
-            marks = self._io_marks()
-            schedule, selective = self._schedule(active_ids, active_ratio)
-            if not schedule:
-                converged = True
-                break
-            if self.batched:
-                # bill this sweep only to columns still holding a frontier
-                col_iters += col_live
-            x = self._gather_fn(src, self._out_deg_dev)
-            # iteration number as a device scalar: same shape/dtype every
-            # sweep, so phase-dependent batched posts never retrace
-            it_dev = jnp.int32(it) if self.batched else None
-            dst, changed = self._sweep(x, src, aux_dev, it_dev, schedule,
-                                       epoch_check)
-            last_changed = changed
-            if self.batched:
-                col_live = changed.any(axis=0)
-                row_active = changed.any(axis=1)
-            else:
-                row_active = changed
-            active_ids = np.nonzero(row_active)[0]
-            active_ratio = active_ids.size / self.n
-            src = dst
+            with span("graphmp.sweep", sweep=it) as sweep:
+                marks = self._io_marks()
+                with span("graphmp.schedule", sweep=it):
+                    schedule, selective = self._schedule(active_ids,
+                                                         active_ratio)
+                if not schedule:
+                    converged = True
+                    break
+                if self.batched:
+                    # bill this sweep only to columns still holding a frontier
+                    col_iters += col_live
+                with span("graphmp.gather", sweep=it):
+                    x = self._gather_fn(src, self._out_deg_dev)
+                # iteration number as a device scalar: same shape/dtype every
+                # sweep, so phase-dependent batched posts never retrace
+                it_dev = jnp.int32(it) if self.batched else None
+                dst, changed = self._sweep(x, src, aux_dev, it_dev, schedule,
+                                           epoch_check, it)
+                last_changed = changed
+                with span("graphmp.schedule", sweep=it):
+                    if self.batched:
+                        col_live = changed.any(axis=0)
+                        row_active = changed.any(axis=1)
+                    else:
+                        row_active = changed
+                    active_ids = np.nonzero(row_active)[0]
+                active_ratio = active_ids.size / self.n
+                src = dst
             stats = IterationStats(
                 iteration=it,
-                seconds=time.time() - t0,
+                seconds=sweep.seconds,
                 active_ratio=active_ratio,
                 shards_processed=len(schedule),
                 shards_skipped=self.P - len(schedule),

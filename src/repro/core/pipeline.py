@@ -23,7 +23,11 @@ cache partition, with per-lane ``stats`` summing to the engine aggregates.)
 ``stats`` separates the two sides of the overlap: ``stall_seconds`` is time
 the consumer spent blocked waiting on the queue (what prefetch is supposed
 to drive to zero) and ``fetch_seconds`` is background time spent producing
-shards (what it hides).
+shards (what it hides), of which ``stage_seconds`` went to staging;
+``h2d_bytes`` counts the bytes staging sent to the device.  Each is the
+duration of a span (``repro.core.spans``): ``graphmp.wait`` on the
+consumer, ``graphmp.fetch`` and ``graphmp.stage`` on the worker, carrying
+the ``sweep`` and ``shard`` they served.
 
 Memory interplay with the two-tier cache (core/cache.py): the worker's
 ``fetch`` is ``cache.get``, which may promote/demote/evict — every such
@@ -42,27 +46,30 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
-import time
 from typing import Any, Callable, Iterator, Sequence
 
 from repro.core.shards import ELLShard
+from repro.core.spans import Counters, span
 
 _DONE = object()
 
 
 @dataclasses.dataclass
-class PipelineStats:
+class PipelineStats(Counters):
     """Producer/consumer accounting.
 
-    ``shards``/``stall_seconds``/``fetch_seconds`` are lifetime
-    accumulators; ``staged_bytes`` is the host bytes of shards currently
-    staged but not yet consumed (bounded by depth × max shard bytes) and
-    ``staged_peak_bytes`` its lifetime high-water mark.
+    ``shards``/``stall_seconds``/``fetch_seconds``/``stage_seconds``/
+    ``h2d_bytes`` are lifetime accumulators; ``staged_bytes`` is the host
+    bytes of shards currently staged but not yet consumed (bounded by
+    depth × max shard bytes) and ``staged_peak_bytes`` its lifetime
+    high-water mark.
     """
 
     shards: int = 0           # shards delivered to the consumer
     stall_seconds: float = 0.0  # consumer time blocked on the queue
     fetch_seconds: float = 0.0  # producer time fetching + staging
+    stage_seconds: float = 0.0  # producer time staging (within fetch)
+    h2d_bytes: int = 0          # bytes staging sent to the device
     staged_bytes: int = 0       # staged-but-unconsumed host bytes (in flight)
     staged_peak_bytes: int = 0  # lifetime high-water mark of staged_bytes
 
@@ -84,56 +91,66 @@ class ShardPipeline:
     ``nbytes``: optional ELLShard -> int used to charge staged-but-unconsumed
     shards to ``stats.staged_bytes`` (the pipeline's own memory footprint on
     top of the cache budget).
+    ``h2d``: optional staged -> int, the bytes ``stage`` sent to the device,
+    added to ``stats.h2d_bytes``.
     """
 
     def __init__(self, fetch: Callable[[int], ELLShard], depth: int = 0,
                  stage: Callable[[ELLShard], Any] | None = None,
-                 nbytes: Callable[[ELLShard], int] | None = None):
+                 nbytes: Callable[[ELLShard], int] | None = None,
+                 h2d: Callable[[Any], int] | None = None):
         if depth < 0:
             raise ValueError(f"prefetch depth must be >= 0, got {depth}")
         self.fetch = fetch
         self.stage = stage
         self.nbytes = nbytes
+        self.h2d = h2d
         self.depth = int(depth)
         self.stats = PipelineStats()
-        self._stats_lock = threading.Lock()  # producer + consumer both charge
 
     def _charge(self, n: int) -> None:
-        with self._stats_lock:
-            self.stats.staged_bytes += n
-            self.stats.staged_peak_bytes = max(self.stats.staged_peak_bytes,
-                                               self.stats.staged_bytes)
+        st = self.stats
+        with st.lock:  # producer + consumer both charge
+            st.staged_bytes += n
+            st.staged_peak_bytes = max(st.staged_peak_bytes, st.staged_bytes)
 
-    def _produce(self, p: int,
-                 check: Callable[[int], None] | None) -> tuple[int, ELLShard, Any, int]:
-        t0 = time.perf_counter()
-        if check is not None:
-            check(p)  # epoch pin: refuse to stage a shard from a newer epoch
-        shard = self.fetch(p)
-        staged = self.stage(shard) if self.stage is not None else None
-        held = self.nbytes(shard) if self.nbytes is not None else 0
-        self._charge(held)
-        self.stats.fetch_seconds += time.perf_counter() - t0
+    def _produce(self, p: int, check: Callable[[int], None] | None,
+                 sweep: int) -> tuple[int, ELLShard, Any, int]:
+        with span("graphmp.fetch", self.stats, "fetch_seconds", sweep=sweep,
+                  shard=p):
+            if check is not None:
+                check(p)  # epoch pin: refuse to stage a shard from a newer epoch
+            shard = self.fetch(p)
+            staged = None
+            if self.stage is not None:
+                with span("graphmp.stage", self.stats, "stage_seconds",
+                          shard=p):
+                    staged = self.stage(shard)
+                if self.h2d is not None:
+                    self.stats.bump(h2d_bytes=self.h2d(staged))
+            held = self.nbytes(shard) if self.nbytes is not None else 0
+            self._charge(held)
         return p, shard, staged, held
 
     def stream(self, schedule: Sequence[int],
-               check: Callable[[int], None] | None = None,
+               check: Callable[[int], None] | None = None, sweep: int = 0,
                ) -> Iterator[tuple[int, ELLShard, Any]]:
         """Yield every shard of ``schedule`` in order, prefetching ahead.
 
         ``check`` (optional) runs on the producer immediately before each
         fetch; the engine passes its epoch-pin assertion so a mid-run graph
         mutation raises ``ConcurrentMutationError`` instead of silently
-        staging a shard from a newer epoch into an older run.
+        staging a shard from a newer epoch into an older run.  ``sweep``
+        names the iteration the stream serves in the spans it writes.
         """
         # a single-shard schedule has nothing to overlap with — skip the
         # worker thread (same order, same accounting, no spawn cost)
         if self.depth == 0 or len(schedule) < 2:
             for p in schedule:
-                t0 = time.perf_counter()
-                pid, shard, staged, held = self._produce(p, check)
                 # synchronous path: the consumer IS stalled for the whole fetch
-                self.stats.stall_seconds += time.perf_counter() - t0
+                with span("graphmp.wait", self.stats, "stall_seconds",
+                          sweep=sweep, shard=p):
+                    pid, shard, staged, held = self._produce(p, check, sweep)
                 self.stats.shards += 1
                 self._charge(-held)  # delivered: no longer in flight
                 yield pid, shard, staged
@@ -147,7 +164,7 @@ class ShardPipeline:
                 for p in schedule:
                     if cancel.is_set():
                         return
-                    q.put(self._produce(p, check))
+                    q.put(self._produce(p, check, sweep))
                 q.put(_DONE)
             except BaseException as exc:  # noqa: BLE001 — forwarded, re-raised
                 q.put(_Failure(exc))
@@ -155,18 +172,22 @@ class ShardPipeline:
         t = threading.Thread(target=worker, name="shard-prefetch", daemon=True)
         t.start()
         try:
-            while True:
-                t0 = time.perf_counter()
-                item = q.get()
-                self.stats.stall_seconds += time.perf_counter() - t0
-                if item is _DONE:
-                    return
+            for p in schedule:
+                with span("graphmp.wait", self.stats, "stall_seconds",
+                          sweep=sweep, shard=p):
+                    item = q.get()
                 if isinstance(item, _Failure):
                     raise item.exc
                 pid, shard, staged, held = item
                 self.stats.shards += 1
                 self._charge(-held)  # delivered: no longer in flight
                 yield pid, shard, staged
+            # the worker's end marker (or its failure) follows the last shard
+            with span("graphmp.wait", self.stats, "stall_seconds",
+                      sweep=sweep):
+                item = q.get()
+            if isinstance(item, _Failure):
+                raise item.exc
         finally:
             cancel.set()
             # unblock a worker parked on q.put, then reap it; de-charge

@@ -672,7 +672,8 @@ class GraphSession:
           partitioned cache's per-partition sub-reports flatten too);
         * ``{prefix}.engine.*`` — an ``iteration_observers`` tap converting
           every ``IterationStats`` into counters (iterations,
-          disk_bytes, edges_processed, stall/fetch/decode-saved seconds,
+          disk_bytes, edges_processed, stall/fetch/stage/decode-saved
+          seconds, h2d_bytes staged to the device,
           per-device ``engine.devN.*`` splits for sharded runs), gauges
           (last active_ratio / cache_hit_ratio), and an
           ``{prefix}.engine.iteration_s`` histogram of sweep durations.
@@ -693,6 +694,8 @@ class GraphSession:
             hub.counter(f"{eng}.shards_skipped").inc(stats.shards_skipped)
             hub.counter(f"{eng}.stall_seconds").inc(stats.stall_seconds)
             hub.counter(f"{eng}.fetch_seconds").inc(stats.fetch_seconds)
+            hub.counter(f"{eng}.stage_seconds").inc(stats.stage_seconds)
+            hub.counter(f"{eng}.h2d_bytes").inc(stats.h2d_bytes)
             hub.counter(f"{eng}.decode_seconds_saved").inc(
                 stats.decode_seconds_saved)
             hub.gauge(f"{eng}.active_ratio").set(stats.active_ratio)
