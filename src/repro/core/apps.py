@@ -612,7 +612,7 @@ def random_walks(sources=(0,), length: int = 8,
     standard uniform random walk).  Each step looks the current vertex's
     shard up through the session's shared compressed cache (``cache.get``
     — the walk IS the cache workload) and picks among its neighbors in
-    canonical ELL order.
+    CSR order (``ELLShard.neighbors``).
 
     The per-step choice uses a counter-based Philox stream keyed by
     (seed, source) with the step index as the counter block, so every
@@ -656,9 +656,7 @@ def random_walks(sources=(0,), length: int = 8,
                 v = int(cur[k])
                 p = int(np.searchsorted(intervals, v, side="right")) - 1
                 shard = session.cache.get(p)
-                rows = np.nonzero(shard.row_map == v - shard.start_vertex)[0]
-                nbrs = shard.cols[rows].ravel()
-                nbrs = nbrs[nbrs >= 0]  # canonical ELL order
+                nbrs = shard.neighbors(v - shard.start_vertex)  # CSR order
                 edges += int(nbrs.size)
                 if nbrs.size == 0:
                     alive[k] = False  # dead end: the walk halts

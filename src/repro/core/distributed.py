@@ -28,8 +28,8 @@ Selective scheduling stays host-side: the per-shard Bloom filters are KBs
 and REPLICATED, so every host computes the identical skip schedule with no
 coordination (core/bloom.py).  Results are bitwise-identical to the
 single-device engine at any device count — the same per-shard kernels run
-with identity padding that cannot perturb f32 reductions (pow2 zero-pad on
-the fold axis, masked rows routed to a discarded segment).
+with identity padding that cannot perturb f32 reductions (sentinel rows in
+no slice, slices mapping no virtual row).
 
 ``DistributedVSW`` — the all-resident prototype kept for mesh-semantics
 tests and as the minimal reference: the WHOLE edge set is partitioned onto
@@ -54,13 +54,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.apps import VertexProgram, get_app
 from repro.core.bloom import BloomFilter
 from repro.core.cache import PartitionedShardCache
-from repro.core.engine import EngineConfig, VSWEngine
+from repro.core.engine import EngineConfig, VSWEngine, make_shard_step
 from repro.core.pipeline import ShardPipeline
-from repro.core.shards import (LANE, SUBLANE, ELLShard, build_csr_shards,
-                               csr_to_ell, dequantize_edge_vals)
+from repro.core.shards import (GROUP_ROWS, LANE, ROW_ALIGN, ELLShard,
+                               build_csr_shards, csr_to_ell,
+                               dequantize_edge_vals)
 from repro.core.spans import span
 from repro.dist.context import make_data_mesh
-from repro.kernels.spmv.ops import ell_spmv, ell_spmv_batch
+from repro.kernels.spmv.ops import ell_spmv
 
 
 # ---------------------------------------------------------------------------
@@ -106,6 +107,33 @@ def assign_shards(intervals: np.ndarray, shard_nnz, num_devices: int
 
 
 # ---------------------------------------------------------------------------
+def stack_layouts(parts, vdt=np.float32):
+    """Stack per-device ELL layouts ``(cols, vals, slices, row_map)`` (None:
+    a device with no shard) into common [D, L, C] / [D, L / GROUP_ROWS] /
+    [D, S*C] arrays.  The padding is reduce-identity: padded rows hold
+    cols -1 and belong to no slice (group id S, which the fold drops),
+    padded slices map no virtual row (row_map -1)."""
+    real = [p for p in parts if p is not None]
+    L = max((p[0].shape[0] for p in real), default=ROW_ALIGN)
+    C = max((p[0].shape[1] for p in real), default=LANE)
+    S = max((p[3].shape[0] // C for p in real), default=1)
+    D = len(parts)
+    cols = np.full((D, L, C), -1, dtype=np.int32)
+    vals = np.zeros((D, L, C), dtype=vdt)
+    slices = np.full((D, L // GROUP_ROWS), S, dtype=np.int32)
+    row_map = np.full((D, S * C), -1, dtype=np.int32)
+    for d, p in enumerate(parts):
+        if p is None:
+            continue
+        c, v, g, rm = p
+        cols[d, :c.shape[0]] = c
+        vals[d, :v.shape[0]] = v
+        slices[d, :g.shape[0]] = g
+        row_map[d, :rm.shape[0]] = rm
+    return cols, vals, slices, row_map
+
+
+# ---------------------------------------------------------------------------
 class ShardedVSWEngine(VSWEngine):
     """VSWEngine whose edge sweep drives ``config.num_devices`` devices.
 
@@ -119,7 +147,7 @@ class ShardedVSWEngine(VSWEngine):
       (``ShardPipeline`` each, staging host-side on the worker thread);
     * ``_sweep`` splits the Bloom-scheduled shard list by owner and runs it
       in WAVES: wave ``w`` stacks each device's ``w``-th shard into one
-      ``[D, R, W]`` batch, a ``shard_map``'ped step folds all D shards
+      ``[D, L, C]`` batch, a ``shard_map``'ped step folds all D shards
       concurrently (single-writer: device ``d`` only writes its interval),
       then a merge step psums the changed-count and ``all_gather``s the
       per-device frontier blocks back into the replicated value array;
@@ -128,9 +156,9 @@ class ShardedVSWEngine(VSWEngine):
 
     Bitwise identity with the single-device engine holds by construction:
     the same ELL kernels run on the same shards; wave padding appends only
-    reduce-identity material (pow2 zero-padding on the fold axis, padded
-    ELL rows routed to a masked or dropped segment) and the merge takes
-    each row from exactly its owner device.
+    reduce-identity material (sentinel rows in no slice, slices mapping no
+    virtual row) and the merge takes each row from exactly its owner
+    device.
     """
 
     def __init__(self, store, program, config=None, *, cache=None, **kw):
@@ -185,16 +213,17 @@ class ShardedVSWEngine(VSWEngine):
     def _stage(self, shard: ELLShard):
         """Host-side staging only (mmap page-in + copy on the worker
         thread); the device transfer happens at wave assembly, where the
-        wave's common [D, R, W] layout is known."""
+        wave's common [D, L, C] layout is known."""
         return (self._materialize(shard.cols), self._materialize(shard.vals),
-                self._materialize(shard.row_map),
+                shard.group_slices(),
+                self._materialize(shard.staged_row_map(self.slices)),
                 np.array([shard.val_scale, shard.val_zero], dtype=np.float32))
 
     # -- compiled steps ---------------------------------------------------
     def _build_steps(self) -> None:
         super()._build_steps()
         program, n, D = self.program, self.n, self._num_devices
-        semiring, use_pallas = program.semiring, self.use_pallas
+        use_pallas = self.use_pallas
         ax, mesh = self._axis, self._mesh
         rep, shd = P(), P(ax)
         B, lens, per_max = self._bounds, self._block_lens, self._per_max
@@ -206,35 +235,19 @@ class ShardedVSWEngine(VSWEngine):
             lambda s: jnp.broadcast_to(s[None], (D,) + s.shape),
             out_shardings=NamedSharding(mesh, shd))
 
+        # one shard per device: the single-device shard step on the
+        # device's slice of every sharded argument
+        step = make_shard_step(program, n, self.segments, use_pallas,
+                               self.batched)
+        n_rep = 4 if self.batched else 2  # x, src[, aux, it]: replicated
+
+        def wave(dst, *args):
+            rep_args, shd_args = args[:n_rep], args[n_rep:]
+            return step(dst[0], *rep_args, *(a[0] for a in shd_args))[None]
+
+        wave_in = (shd,) + (rep,) * n_rep + (shd,) * 7
+
         if self.batched:
-            has_aux = getattr(program, "make_aux", None) is not None
-            wants_it = getattr(program, "wants_iteration", False)
-
-            def wave(dst, x, src, aux, it, cols, vals, row_map, qp, start,
-                     num_rows):
-                dst, cols, vals, row_map = dst[0], cols[0], vals[0], row_map[0]
-                qp, start, num_rows = qp[0], start[0], num_rows[0]
-                R, K = cols.shape[0], src.shape[1]
-                seg = ell_spmv_batch(x, cols, vals, row_map, R, semiring,
-                                     use_pallas=use_pallas, qparams=qp)
-                old_slice = jax.lax.dynamic_slice(src, (start, 0), (R, K))
-                rows = start + jnp.arange(R)
-                aux_slice = (jax.lax.dynamic_slice(aux, (start, 0), (R, K))
-                             if has_aux else None)
-                if wants_it:
-                    new_slice = program.post(seg, old_slice, rows, n,
-                                             aux_slice, it)
-                else:
-                    new_slice = program.post(seg, old_slice, rows, n,
-                                             aux_slice)
-                new_slice = new_slice.astype(dst.dtype)
-                keep = (jnp.arange(R) < num_rows)[:, None]
-                new_slice = jnp.where(keep, new_slice, old_slice)
-                return jax.lax.dynamic_update_slice(dst, new_slice,
-                                                    (start, 0))[None]
-
-            wave_in = (shd, rep, rep, rep, rep, shd, shd, shd, shd, shd, shd)
-
             def merge(dst, src):
                 dstl = dst[0]
                 d = jax.lax.axis_index(ax)
@@ -254,21 +267,6 @@ class ShardedVSWEngine(VSWEngine):
                             (int(B[dd]), 0))
                 return new_full, cnt
         else:
-            def wave(dst, x, src, cols, vals, row_map, qp, start, num_rows):
-                dst, cols, vals, row_map = dst[0], cols[0], vals[0], row_map[0]
-                qp, start, num_rows = qp[0], start[0], num_rows[0]
-                R = cols.shape[0]
-                seg = ell_spmv(x, cols, vals, row_map, R, semiring,
-                               use_pallas=use_pallas, qparams=qp)
-                old_slice = jax.lax.dynamic_slice(src, (start,), (R,))
-                new_slice = program.post(seg, old_slice, n).astype(dst.dtype)
-                keep = jnp.arange(R) < num_rows
-                new_slice = jnp.where(keep, new_slice, old_slice)
-                return jax.lax.dynamic_update_slice(dst, new_slice,
-                                                    (start,))[None]
-
-            wave_in = (shd, rep, rep, shd, shd, shd, shd, shd, shd)
-
             def merge(dst, src):
                 dstl = dst[0]
                 d = jax.lax.axis_index(ax)
@@ -298,31 +296,21 @@ class ShardedVSWEngine(VSWEngine):
     # -- per-iteration seams ----------------------------------------------
     def _assemble_wave(self, entries):
         """Stack one shard per device (or a dummy) into the wave's common
-        [D, R, W] layout and place it sharded over the mesh.
-
-        Padding is reduce-identity by construction, so results stay bitwise
-        equal to running each shard at its own bucketed shape: cols -1
-        (masked out of the fold; zero-padding a pow2-lane f32 reduction
-        adds +0.0 per lane accumulator), padded ELL rows routed to segment
-        min(num_rows, R) — a keep-masked destination row when it exists,
-        otherwise out of range and dropped by the segment combine.  Dummies
-        (a device with no shard this wave) write their restored old values
-        at ``start = n``, i.e. into the padding region, so they cannot
-        revert a real row updated by an earlier wave.
+        [D, L, C] layout (``stack_layouts``) and place it sharded over the
+        mesh.  The padding is reduce-identity, so results stay bitwise
+        equal to running each shard at its own bucketed shape.  Dummies (a
+        device with no shard this wave) write their restored old values at
+        ``start = n``, i.e. into the padding region, so they cannot revert
+        a real row updated by an earlier wave.
         """
         D = self._num_devices
-        shards = [e[1] for e in entries if e is not None]
-        R = max((s.cols.shape[0] for s in shards), default=SUBLANE)
-        W = max((s.cols.shape[1] for s in shards), default=LANE)
         # one vals dtype per wave (the shard_map step compiles per dtype); a
         # mixed wave — possible mid-migration of a store — dequantizes to
         # float32 on the host and ships identity qparams instead
         vdts = {e[2][1].dtype for e in entries if e is not None}
         mixed = len(vdts) > 1
         vdt = np.float32 if (mixed or not vdts) else vdts.pop()
-        cols = np.full((D, R, W), -1, dtype=np.int32)
-        vals = np.zeros((D, R, W), dtype=vdt)
-        rmap = np.zeros((D, R), dtype=np.int32)
+        parts = [None] * D
         qp = np.tile(np.array([1.0, 0.0], dtype=np.float32), (D, 1))
         start = np.full(D, self.n, dtype=np.int32)
         nrows = np.zeros(D, dtype=np.int32)
@@ -330,20 +318,15 @@ class ShardedVSWEngine(VSWEngine):
             if e is None:
                 continue
             _p, shard, staged = e
-            c, v, rm, q = staged
+            c, v, g, rm, q = staged
             if mixed and v.dtype != np.float32:
                 v = dequantize_edge_vals(v, float(q[0]), float(q[1]))
                 q = np.array([1.0, 0.0], dtype=np.float32)
-            r, w = c.shape
-            nr = int(shard.end_vertex - shard.start_vertex)
-            cols[d, :r, :w] = c
-            vals[d, :r, :w] = v
-            rmap[d, :r] = rm
-            rmap[d, r:] = min(nr, R)
+            parts[d] = (c, v, g, rm)
             qp[d] = q
             start[d] = shard.start_vertex
-            nrows[d] = nr
-        arrays = (cols, vals, rmap, qp, start, nrows)
+            nrows[d] = shard.end_vertex - shard.start_vertex
+        arrays = stack_layouts(parts, vdt) + (qp, start, nrows)
         # each device receives one slice of every array: charge its bytes
         # to the device's lane
         per_device = sum(a.nbytes for a in arrays) // D
@@ -391,7 +374,8 @@ class ShardedVSWEngine(VSWEngine):
         return ([(c.stats.disk_bytes, c.stats.hits, c.stats.misses,
                   c.stats.decode_seconds_saved) for c in self.cache.parts],
                 [(l.stats.stall_seconds, l.stats.fetch_seconds,
-                  l.stats.stage_seconds, l.stats.h2d_bytes)
+                  l.stats.stage_seconds, l.stats.h2d_bytes,
+                  l.stats.ell_slots, l.stats.ell_arcs)
                  for l in self._lanes])
 
     def _io_stats(self, marks) -> dict:
@@ -420,6 +404,10 @@ class ShardedVSWEngine(VSWEngine):
                               for l, m in zip(self._lanes, lane_marks)),
             h2d_bytes=sum(l.stats.h2d_bytes - m[3]
                           for l, m in zip(self._lanes, lane_marks)),
+            ell_slots=sum(l.stats.ell_slots - m[4]
+                          for l, m in zip(self._lanes, lane_marks)),
+            ell_arcs=sum(l.stats.ell_arcs - m[5]
+                         for l, m in zip(self._lanes, lane_marks)),
             decode_seconds_saved=sum(d_saved),
             device_disk_bytes=tuple(d_disk),
             device_stall_seconds=tuple(d_stall),
@@ -440,9 +428,10 @@ class DeviceShardedGraph:
     num_vertices: int          # true |V|
     padded_num_vertices: int   # |V| rounded up to a multiple of num_devices
     num_edges: int
-    cols: np.ndarray           # [D, R, W] int32 (per-device ELL, common shape)
-    vals: np.ndarray           # [D, R, W] float32
-    row_map: np.ndarray        # [D, R] int32 (local row within the device interval)
+    cols: np.ndarray           # [D, L, C] int32 (per-device ELL, common shape)
+    vals: np.ndarray           # [D, L, C] float32
+    slices: np.ndarray         # [D, L / GROUP_ROWS] int32 slice of each row group
+    row_map: np.ndarray        # [D, S*C] int32 (local row within the device interval)
     out_deg: np.ndarray        # [padded_num_vertices] int64 (0 on padding)
     rows_per_device: int       # interval length padded_num_vertices / D
     blooms: list               # per-device source-vertex BloomFilters (replicated)
@@ -474,20 +463,12 @@ def partition_for_mesh(
         sources = np.unique(sub.col)
         blooms.append(BloomFilter.build(
             sources, num_bits=BloomFilter.sized_for(sources.size)))
-    R = max(((e.shape[0] + SUBLANE - 1) // SUBLANE) * SUBLANE for e in ells)
-    W = max(e.shape[1] for e in ells)
-    cols = np.full((num_devices, R, W), -1, dtype=np.int32)
-    vals = np.zeros((num_devices, R, W), dtype=np.float32)
-    row_map = np.zeros((num_devices, R), dtype=np.int32)
-    for d, e in enumerate(ells):
-        r, w = e.shape
-        cols[d, :r, :w] = e.cols
-        vals[d, :r, :w] = e.vals
-        row_map[d, :r] = e.row_map
+    cols, vals, slices, row_map = stack_layouts(
+        [(e.cols, e.vals, e.group_slices(), e.row_map) for e in ells])
     out_deg = np.bincount(src, minlength=n_pad).astype(np.int64)
     return DeviceShardedGraph(
         num_vertices=int(num_vertices), padded_num_vertices=n_pad,
-        num_edges=len(src), cols=cols, vals=vals,
+        num_edges=len(src), cols=cols, vals=vals, slices=slices,
         row_map=row_map, out_deg=out_deg, rows_per_device=per, blooms=blooms,
     )
 
@@ -541,6 +522,8 @@ class DistributedVSW:
         edge_spec = P(axis)
         self._cols = jax.device_put(graph.cols, NamedSharding(mesh, edge_spec))
         self._vals = jax.device_put(graph.vals, NamedSharding(mesh, edge_spec))
+        self._slices = jax.device_put(graph.slices,
+                                      NamedSharding(mesh, edge_spec))
         self._rmap = jax.device_put(graph.row_map, NamedSharding(mesh, edge_spec))
         self._out_deg = jnp.asarray(graph.out_deg.astype(np.float32))
         self._iter_fn = self._build_iter()
@@ -549,15 +532,16 @@ class DistributedVSW:
         program, n, per = self.program, self.n, self.g.rows_per_device
         semiring, use_pallas, axis = program.semiring, self.use_pallas, self.axis
 
-        def device_iter(src_full, out_deg, cols, vals, row_map, flags):
+        def device_iter(src_full, out_deg, cols, vals, slices, row_map, flags):
             # shard_map gives per-device blocks with a leading length-1 axis
-            cols, vals, row_map, flag = cols[0], vals[0], row_map[0], flags[0]
+            cols, vals, flag = cols[0], vals[0], flags[0]
+            slices, row_map = slices[0], row_map[0]
             x = program.gather_transform(src_full, out_deg)
-            R = cols.shape[0]
-            seg = ell_spmv(x, cols, vals, row_map, R, semiring, use_pallas=use_pallas)
+            seg = ell_spmv(x, cols, vals, slices, row_map, per, semiring,
+                           use_pallas=use_pallas)
             d = jax.lax.axis_index(axis)
             old_own = jax.lax.dynamic_slice(src_full, (d * per,), (per,))
-            new_own = program.post(seg[:per], old_own, n).astype(src_full.dtype)
+            new_own = program.post(seg, old_own, n).astype(src_full.dtype)
             # Bloom-skipped device: keep the old interval verbatim
             new_own = jnp.where(flag != 0, new_own, old_own)
             # padding rows (ids >= n) never count as changed
@@ -573,8 +557,7 @@ class DistributedVSW:
         fn = jax.shard_map(
             device_iter,
             mesh=self.mesh,
-            in_specs=(spec_rep, spec_rep, P(self.axis), P(self.axis),
-                      P(self.axis), P(self.axis)),
+            in_specs=(spec_rep, spec_rep) + (P(self.axis),) * 5,
             out_specs=(spec_rep, spec_rep, spec_rep),
             check_vma=False,
         )
@@ -603,8 +586,8 @@ class DistributedVSW:
                 break  # every device Bloom-skipped: nothing can change
             flags_dev = jax.device_put(flags.astype(np.int32), flag_sharding)
             src, changed_full, changed_total = self._iter_fn(
-                src, self._out_deg, self._cols, self._vals, self._rmap,
-                flags_dev)
+                src, self._out_deg, self._cols, self._vals, self._slices,
+                self._rmap, flags_dev)
             it_done = it
             if int(changed_total) == 0:
                 break
@@ -615,25 +598,30 @@ class DistributedVSW:
 
 
 def spmv_2d(x: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
-            row_map: jnp.ndarray, semiring: str, mesh: Mesh,
-            dst_axis: str = "data", src_axis: str = "model",
+            slices: jnp.ndarray, row_map: jnp.ndarray, num_segments: int,
+            semiring: str, mesh: Mesh, dst_axis: str = "data",
+            src_axis: str = "model",
             use_pallas: bool | str = "auto") -> jnp.ndarray:
     """2-D partitioned SpMV: dst intervals over `dst_axis`, source ranges over
     `src_axis`.  Each device folds its (dst-block × src-range) ELL tile; a
     psum over `src_axis` combines partials (min-semirings use pmin via
     all_gather+fold).  x is sharded by source range; cols are *local* source
-    indices.  Returns per-dst-interval partials sharded over `dst_axis`."""
+    indices.  Returns per-dst-interval partials [num_segments] sharded over
+    `dst_axis`."""
+    from repro.core.semiring import SEMIRINGS
+    from repro.kernels.spmv.ops import ell_gather_fold
+    from repro.kernels.spmv.ref import slice_combine
 
-    def local(x_blk, cols_b, vals_b, row_map_b):
-        # x: [n] split over src_axis -> [n/S]; edge tensors: [D, S, R, W] -> [1, 1, R, W]
-        cols_b, vals_b, row_map_b = cols_b[0, 0], vals_b[0, 0], row_map_b[0, 0]
-        from repro.kernels.spmv.ops import ell_gather_fold
-        partial_rows = ell_gather_fold(x_blk, cols_b, vals_b, semiring,
-                                       use_pallas=use_pallas).reshape(-1)
-        from repro.kernels.spmv.ref import segment_combine
-        seg = segment_combine(partial_rows, row_map_b, cols_b.shape[0], semiring)
-        from repro.core.semiring import SEMIRINGS
-        sem = SEMIRINGS[semiring]
+    sem = SEMIRINGS[semiring]
+
+    def local(x_blk, cols_b, vals_b, slices_b, row_map_b):
+        # x: [n] split over src_axis -> [n/S]; edge tensors: [D, S, ...] -> [1, 1, ...]
+        cols_b, vals_b = cols_b[0, 0], vals_b[0, 0]
+        slices_b, row_map_b = slices_b[0, 0], row_map_b[0, 0]
+        groups = ell_gather_fold(x_blk, cols_b, vals_b, semiring,
+                                 use_pallas=use_pallas)
+        seg = slice_combine(groups[None], slices_b, row_map_b, num_segments,
+                            semiring)[:, 0]
         if sem.is_plus:
             seg = jax.lax.psum(seg, src_axis)
         else:
@@ -641,10 +629,11 @@ def spmv_2d(x: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
             seg = (jnp.max if sem.is_max else jnp.min)(allseg, axis=0)
         return seg[None]
 
+    edge = P(dst_axis, src_axis)
     fn = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(src_axis), P(dst_axis, src_axis), P(dst_axis, src_axis), P(dst_axis, src_axis)),
+        in_specs=(P(src_axis), edge, edge, edge, edge),
         out_specs=P(dst_axis),
         check_vma=False,
     )
-    return fn(x, cols, vals, row_map)
+    return fn(x, cols, vals, slices, row_map)

@@ -48,7 +48,7 @@ import numpy as np
 from repro.core.apps import BatchedVertexProgram, VertexProgram
 from repro.core.cache import CompressedShardCache
 from repro.core.pipeline import ShardPipeline
-from repro.core.shards import ELLShard
+from repro.core.shards import ELLShard, segment_rows, store_slices
 from repro.core.spans import span
 from repro.graph.source import ConcurrentMutationError, ShardSource
 from repro.kernels.spmv.ops import ell_spmv, ell_spmv_batch
@@ -233,6 +233,8 @@ class IterationStats:
     fetch_seconds: float = 0.0  # fetch+stage time (overlapped when prefetching)
     stage_seconds: float = 0.0  # of which host->device staging
     h2d_bytes: int = 0          # bytes staged to the device
+    ell_slots: int = 0          # ELL slots staged, padding included
+    ell_arcs: int = 0           # of which hold an edge (the shards' nnz)
     decode_seconds_saved: float = 0.0  # decompression cost hot-tier hits skipped
     # multi-device runs only (empty tuples otherwise): per-device splits of
     # the aggregates above — one entry per device, summing (disk/fetch) or
@@ -330,6 +332,58 @@ class BatchRunResult(RunResult):
         return [self.column(k) for k in range(self.num_columns)]
 
 
+def make_shard_step(program, n: int, segments: int, use_pallas="auto",
+                    batched: bool = False):
+    """The device work of one shard, to be jitted (``jit_shard_step``):
+    gather, fold and slice combine into ``segments`` partials, then
+    ``program.post`` on the destination rows ``[start, start + segments)``,
+    keeping rows at or past ``num_rows`` (the next interval's) as they
+    were.  Signature ``(dst, x, src, [aux, it,] cols, vals, slices,
+    row_map, qp, start, num_rows) -> dst`` (aux and it for batched
+    programs)."""
+    semiring, R = program.semiring, segments
+    if not batched:
+        def shard_step(dst, x, src, cols, vals, slices, row_map, qp, start,
+                       num_rows):
+            seg = ell_spmv(x, cols, vals, slices, row_map, R, semiring,
+                           use_pallas=use_pallas, qparams=qp)
+            old_slice = jax.lax.dynamic_slice(src, (start,), (R,))
+            new_slice = program.post(seg, old_slice, n).astype(dst.dtype)
+            keep = jnp.arange(R) < num_rows
+            new_slice = jnp.where(keep, new_slice, old_slice)
+            return jax.lax.dynamic_update_slice(dst, new_slice, (start,))
+        return shard_step
+
+    # [n_pad, K] value matrix: one edge sweep advances K frontiers.
+    # Per-column constants (PPR's reset vector) arrive through the runtime
+    # ``aux`` argument so the compiled step — and therefore the engine — is
+    # shared across source/seed sets (jit_signature).
+    has_aux = getattr(program, "make_aux", None) is not None
+    # phase-dependent programs (triangle counting's two-pass probe)
+    # additionally receive the iteration number as a DEVICE scalar — a
+    # runtime argument, so every iteration reuses one compiled step
+    wants_it = getattr(program, "wants_iteration", False)
+
+    def shard_step(dst, x, src, aux, it, cols, vals, slices, row_map, qp,
+                   start, num_rows):
+        K = src.shape[1]
+        seg = ell_spmv_batch(x, cols, vals, slices, row_map, R, semiring,
+                             use_pallas=use_pallas, qparams=qp)
+        old_slice = jax.lax.dynamic_slice(src, (start, 0), (R, K))
+        rows = start + jnp.arange(R)
+        aux_slice = (jax.lax.dynamic_slice(aux, (start, 0), (R, K))
+                     if has_aux else None)
+        if wants_it:
+            new_slice = program.post(seg, old_slice, rows, n, aux_slice, it)
+        else:
+            new_slice = program.post(seg, old_slice, rows, n, aux_slice)
+        new_slice = new_slice.astype(dst.dtype)
+        keep = (jnp.arange(R) < num_rows)[:, None]
+        new_slice = jnp.where(keep, new_slice, old_slice)
+        return jax.lax.dynamic_update_slice(dst, new_slice, (start, 0))
+    return shard_step
+
+
 _LEGACY_KWARGS = ("cache_mode", "cache_budget_bytes", "selective_threshold",
                   "use_pallas", "preload", "prefetch_depth")
 
@@ -409,9 +463,12 @@ class VSWEngine:
         self.P = store.num_shards
         shard_meta = store.properties["shards"]
         self._shard_nnz = [int(m.get("nnz", 0)) for m in shard_meta]
-        self.max_rows = max((m["rows"] for m in shard_meta), default=8)
-        # pad the vertex arrays so every dynamic_slice of length R is in-bounds
-        self.n_pad = n_pad if n_pad is not None else self.n + self.max_rows
+        self.slices = store_slices(shard_meta)
+        # every shard step folds into, and updates, this many rows from its
+        # interval's start (intervals never move, so neither does it)
+        self.segments = segment_rows(self.intervals)
+        # pad the vertex arrays so every such slice is in-bounds
+        self.n_pad = n_pad if n_pad is not None else self.n + self.segments
         if out_deg_dev is not None:
             self._out_deg_dev = out_deg_dev
         else:
@@ -450,8 +507,8 @@ class VSWEngine:
 
     # ------------------------------------------------------------------
     def _build_steps(self) -> None:
-        program, n = self.program, self.n
-        semiring, use_pallas = self.program.semiring, self.use_pallas
+        program, n, R = self.program, self.n, self.segments
+        use_pallas = self.use_pallas
 
         # out-degrees arrive as a RUNTIME argument, never a closure constant:
         # a jit closure would bake the degree array at trace time and
@@ -460,51 +517,10 @@ class VSWEngine:
         def gather_fn(values, out_deg):
             return program.gather_transform(values, out_deg)
 
-        if self.batched:
-            # [n_pad, K] value matrix: one edge sweep advances K frontiers.
-            # Per-column constants (PPR's reset vector) arrive through the
-            # runtime ``aux`` argument so the compiled step — and therefore
-            # the engine — is shared across source/seed sets (jit_signature).
-            has_aux = getattr(program, "make_aux", None) is not None
-            # phase-dependent programs (triangle counting's two-pass probe)
-            # additionally receive the iteration number as a DEVICE scalar —
-            # a runtime argument, so every iteration reuses one compiled step
-            wants_it = getattr(program, "wants_iteration", False)
-
-            def shard_step(dst, x, src, aux, it, cols, vals, row_map, qp,
-                           start, num_rows):
-                R = cols.shape[0]
-                K = src.shape[1]
-                seg = ell_spmv_batch(x, cols, vals, row_map, R, semiring,
-                                     use_pallas=use_pallas, qparams=qp)
-                old_slice = jax.lax.dynamic_slice(src, (start, 0), (R, K))
-                rows = start + jnp.arange(R)
-                aux_slice = (jax.lax.dynamic_slice(aux, (start, 0), (R, K))
-                             if has_aux else None)
-                if wants_it:
-                    new_slice = program.post(seg, old_slice, rows, n,
-                                             aux_slice, it)
-                else:
-                    new_slice = program.post(seg, old_slice, rows, n,
-                                             aux_slice)
-                new_slice = new_slice.astype(dst.dtype)
-                keep = (jnp.arange(R) < num_rows)[:, None]
-                new_slice = jnp.where(keep, new_slice, old_slice)
-                return jax.lax.dynamic_update_slice(dst, new_slice, (start, 0))
-        else:
-            def shard_step(dst, x, src, cols, vals, row_map, qp, start,
-                           num_rows):
-                R = cols.shape[0]
-                seg = ell_spmv(x, cols, vals, row_map, R, semiring,
-                               use_pallas=use_pallas, qparams=qp)
-                old_slice = jax.lax.dynamic_slice(src, (start,), (R,))
-                new_slice = program.post(seg, old_slice, n).astype(dst.dtype)
-                keep = jnp.arange(R) < num_rows
-                new_slice = jnp.where(keep, new_slice, old_slice)
-                return jax.lax.dynamic_update_slice(dst, new_slice, (start,))
-
-        # one jit per ELL (R, W) bucket happens automatically via shape polymorphism
-        self._shard_step = jax.jit(shard_step, donate_argnums=(0,))
+        # one compile per ELL shape (rows L, slices S): both are bucketed
+        self._shard_step = jax.jit(
+            make_shard_step(program, n, R, use_pallas, self.batched),
+            donate_argnums=(0,))
         self._gather_fn = gather_fn
 
         @jax.jit
@@ -593,8 +609,7 @@ class VSWEngine:
             self.in_deg, self.out_deg = self.store.read_vertex_info()
             shard_meta = self.store.properties["shards"]
             self._shard_nnz = [int(m.get("nnz", 0)) for m in shard_meta]
-            self.max_rows = max((m["rows"] for m in shard_meta), default=8)
-            self.n_pad = max(self.n_pad, self.n + self.max_rows)
+            self.slices = store_slices(shard_meta)
             self._out_deg_dev = jnp.asarray(
                 np.pad(self.out_deg,
                        (0, self.n_pad - self.n)).astype(np.float32))
@@ -619,7 +634,9 @@ class VSWEngine:
         so the transfer overlaps the previous shard's SpMV."""
         return (jnp.asarray(self._materialize(shard.cols)),
                 jnp.asarray(self._materialize(shard.vals)),
-                jnp.asarray(self._materialize(shard.row_map)),
+                jnp.asarray(shard.group_slices()),
+                jnp.asarray(self._materialize(
+                    shard.staged_row_map(self.slices))),
                 jnp.asarray(np.array([shard.val_scale, shard.val_zero],
                                      dtype=np.float32)))
 
@@ -641,11 +658,12 @@ class VSWEngine:
         cs, ps = self.cache.stats, self._pipeline.stats
         return (cs.disk_bytes, cs.hits, cs.misses, cs.decode_seconds_saved,
                 ps.stall_seconds, ps.fetch_seconds, ps.stage_seconds,
-                ps.h2d_bytes)
+                ps.h2d_bytes, ps.ell_slots, ps.ell_arcs)
 
     def _io_stats(self, marks) -> dict:
         """IterationStats I/O fields as deltas against ``marks``."""
-        disk0, hits0, misses0, saved0, stall0, fetch0, stage0, h2d0 = marks
+        (disk0, hits0, misses0, saved0, stall0, fetch0, stage0, h2d0,
+         slots0, arcs0) = marks
         cs, ps = self.cache.stats, self._pipeline.stats
         d_hits = cs.hits - hits0
         d_total = d_hits + cs.misses - misses0
@@ -656,6 +674,8 @@ class VSWEngine:
             fetch_seconds=ps.fetch_seconds - fetch0,
             stage_seconds=ps.stage_seconds - stage0,
             h2d_bytes=ps.h2d_bytes - h2d0,
+            ell_slots=ps.ell_slots - slots0,
+            ell_arcs=ps.ell_arcs - arcs0,
             decode_seconds_saved=cs.decode_seconds_saved - saved0,
         )
 
@@ -668,9 +688,7 @@ class VSWEngine:
         for p, shard, dev in self._pipeline.stream(schedule,
                                                    check=epoch_check,
                                                    sweep=it):
-            cols_dev, vals_dev, row_map_dev, qp_dev = dev
-            tail = (cols_dev, vals_dev, row_map_dev, qp_dev,
-                    shard.start_vertex,
+            tail = (*dev, shard.start_vertex,
                     shard.end_vertex - shard.start_vertex)
             with span("graphmp.step", sweep=it, shard=p):
                 if self.batched:

@@ -23,11 +23,12 @@ cache partition, with per-lane ``stats`` summing to the engine aggregates.)
 ``stats`` separates the two sides of the overlap: ``stall_seconds`` is time
 the consumer spent blocked waiting on the queue (what prefetch is supposed
 to drive to zero) and ``fetch_seconds`` is background time spent producing
-shards (what it hides), of which ``stage_seconds`` went to staging;
-``h2d_bytes`` counts the bytes staging sent to the device.  Each is the
-duration of a span (``repro.core.spans``): ``graphmp.wait`` on the
+shards (what it hides), of which ``stage_seconds`` went to staging.  Each
+is the duration of a span (``repro.core.spans``): ``graphmp.wait`` on the
 consumer, ``graphmp.fetch`` and ``graphmp.stage`` on the worker, carrying
-the ``sweep`` and ``shard`` they served.
+the ``sweep`` and ``shard`` they served.  ``h2d_bytes`` counts the bytes
+staging sent to the device, ``ell_slots`` the ELL slots of the shards it
+staged and ``ell_arcs`` the edges those slots hold.
 
 Memory interplay with the two-tier cache (core/cache.py): the worker's
 ``fetch`` is ``cache.get``, which may promote/demote/evict — every such
@@ -70,6 +71,8 @@ class PipelineStats(Counters):
     fetch_seconds: float = 0.0  # producer time fetching + staging
     stage_seconds: float = 0.0  # producer time staging (within fetch)
     h2d_bytes: int = 0          # bytes staging sent to the device
+    ell_slots: int = 0          # ELL slots of the staged shards
+    ell_arcs: int = 0           # edges those slots hold
     staged_bytes: int = 0       # staged-but-unconsumed host bytes (in flight)
     staged_peak_bytes: int = 0  # lifetime high-water mark of staged_bytes
 
@@ -128,6 +131,8 @@ class ShardPipeline:
                     staged = self.stage(shard)
                 if self.h2d is not None:
                     self.stats.bump(h2d_bytes=self.h2d(staged))
+                self.stats.bump(ell_slots=int(shard.cols.size),
+                                ell_arcs=int(shard.nnz))
             held = self.nbytes(shard) if self.nbytes is not None else 0
             self._charge(held)
         return p, shard, staged, held

@@ -29,7 +29,7 @@ Array = jnp.ndarray
 @dataclasses.dataclass(frozen=True)
 class Semiring:
     name: str
-    # reduce(a, b) -> elementwise monoid used to fold the ELL width dim
+    # reduce(a, b) -> elementwise monoid used to fold a virtual row
     reduce: Callable[[Array, Array], Array]
     # combine(edge_val, src_val) -> contribution of one edge
     combine: Callable[[Array, Array], Array]

@@ -1,4 +1,4 @@
-"""Graph sharding: vertex intervals (Algorithm 1), CSR shards, blocked-ELL.
+"""Graph sharding: vertex intervals (Algorithm 1), CSR shards, sliced ELL.
 
 Faithful to the paper's §2.2:
   * vertices are split into P disjoint intervals; shard(i) holds every edge
@@ -7,23 +7,30 @@ Faithful to the paper's §2.2:
     ``threshold_edge_num`` edges (paper default: 20M edges ≈ 80MB);
   * edges inside a shard are grouped by destination and stored in CSR.
 
-TPU adaptation (DESIGN.md §4): CSR rows are re-laid out as **blocked-ELL** —
-``(rows, width)`` rectangles with lane-aligned width (multiple of 128) and
-sentinel columns ``col < 0``.  Rows whose degree exceeds the shard's ELL
-width are wrapped onto extra ELL rows mapped to the same destination vertex
-(`row_map`), which is how we absorb power-law skew without padding the whole
-shard to the max in-degree.  The reduce over duplicated rows re-applies the
-semiring, preserving exact results for +, min.
+TPU adaptation: CSR rows are re-laid out as a **sliced ELL** (SELL-C-σ)
+with C = 128 rows along the lanes (:class:`ELLShard`).  Empty rows get no
+slot; rows longer than ``max_width`` wrap onto several virtual rows mapped
+to the same destination (``row_map``), which absorbs power-law skew; the
+virtual rows are sorted by length, so each slice of 128 is only as deep as
+its longest row and padding stays a few percent of the edges.  The reduce
+over a destination's virtual rows re-applies the semiring, preserving exact
+results for +, min, max.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator, Sequence
+from typing import ClassVar, Iterator
 
 import numpy as np
 
-LANE = 128  # TPU lane width; ELL width is padded to a multiple of this.
-SUBLANE = 8  # TPU sublane; ELL row count padded to a multiple of this.
+LANE = 128  # TPU lane width: the virtual rows one ELL slice holds.
+SUBLANE = 8  # TPU sublane.
+# Slice depths are multiples of GROUP_ROWS: the fold reduces each lane
+# GROUP_ROWS rows at a time, and a group never straddles two slices.
+GROUP_ROWS = 2
+# Row counts are multiples of ROW_ALIGN: whole int8 (32, 128) tiles, and
+# whole (8, 128) tiles of the fold's group partials.
+ROW_ALIGN = max(32, SUBLANE * GROUP_ROWS)
 
 # Edge-value storage dtypes (GRAPHMP_EDGE_DTYPE / preprocess val_dtype).
 # float32 is the exact baseline; float16/int8 trade bounded error for halved/
@@ -178,32 +185,48 @@ def build_csr_shards(
 
 
 # --------------------------------------------------------------------------
-# Blocked-ELL shard (TPU layout)
+# Sliced ELL shard (TPU layout)
 # --------------------------------------------------------------------------
 @dataclasses.dataclass
 class ELLShard:
-    """TPU-native shard: fixed-width padded rows, sentinel col = -1.
+    """TPU-native shard: a sliced ELL (SELL-C-σ) with C rows along the lanes.
 
-    ``row_map[r]`` gives the *local* destination row (0-based within the
-    interval) that ELL row r accumulates into; heavy CSR rows occupy several
-    consecutive ELL rows.  rows % SUBLANE == 0 and width % LANE == 0.
+    Each non-empty destination row becomes one or more *virtual rows* of at
+    most ``max_width`` edges (a hub wraps onto several); ``row_map[v]`` is
+    the local destination (0-based within the interval) of virtual row v.
+    Virtual rows are sorted longest first and cut into slices of C = lane
+    consecutive virtual rows, one per lane: slice s holds virtual rows
+    [s*C, (s+1)*C) and occupies rows [slice_ptr[s], slice_ptr[s+1]) of
+    ``cols``/``vals``, as deep as its longest virtual row rounded up to
+    ``GROUP_ROWS``; lane j of those rows holds virtual row s*C + j, top
+    down, sentinel col = -1 below its end.  Rows past ``slice_ptr[-1]`` and
+    slices past the last virtual row are padding (row_map -1); an empty
+    destination row owns no slot.
     """
 
     shard_id: int
     start_vertex: int
     end_vertex: int
-    cols: np.ndarray     # [R, W] int32, sentinel -1
-    vals: np.ndarray     # [R, W] float32 | float16 | int8 (see val_scale)
-    row_map: np.ndarray  # [R] int32 — local destination row per ELL row
+    cols: np.ndarray       # [L, C] int32, sentinel -1
+    vals: np.ndarray       # [L, C] float32 | float16 | int8 (see val_scale)
+    row_map: np.ndarray    # [S*C] int32 — local destination per virtual row
+    slice_ptr: np.ndarray  # [S+1] int32 — first row of each slice
     nnz: int
     # Affine dequantization parameters for non-float32 ``vals`` (identity for
     # float32): true value = (vals.astype(f32) - val_zero) * val_scale.
     val_scale: float = 1.0
     val_zero: float = 0.0
+    # the layout's arrays, as the storage backends persist them
+    ARRAYS: ClassVar[tuple[str, ...]] = ("cols", "vals", "row_map",
+                                         "slice_ptr")
 
     @property
     def shape(self) -> tuple[int, int]:
-        return self.cols.shape  # (R, W)
+        return self.cols.shape  # (L, C)
+
+    @property
+    def num_slices(self) -> int:
+        return int(self.slice_ptr.shape[0]) - 1
 
     @property
     def quantized(self) -> bool:
@@ -217,10 +240,59 @@ class ELLShard:
         return self.cols.nbytes + self.vals.nbytes
 
     def decoded_nbytes(self) -> int:
-        """Host bytes of the decoded shard (cols + vals + row_map) — the one
-        definition shared by cache hot-tier accounting and pipeline
-        staged-bytes accounting."""
-        return self.padded_bytes() + self.row_map.nbytes
+        """Host bytes of the decoded shard (cols + vals + row_map +
+        slice_ptr) — the one definition shared by cache hot-tier accounting
+        and pipeline staged-bytes accounting."""
+        return (self.padded_bytes() + self.row_map.nbytes
+                + self.slice_ptr.nbytes)
+
+    def group_slices(self) -> np.ndarray:
+        """[L / GROUP_ROWS] int32: the slice each group of GROUP_ROWS rows
+        belongs to, ascending; padding rows get S, which the fold drops."""
+        depth = np.diff(self.slice_ptr) // GROUP_ROWS
+        ids = np.full(self.cols.shape[0] // GROUP_ROWS, self.num_slices,
+                      dtype=np.int32)
+        ids[: int(depth.sum())] = np.repeat(
+            np.arange(self.num_slices, dtype=np.int32), depth)
+        return ids
+
+    def staged_row_map(self, num_slices: int) -> np.ndarray:
+        """``row_map`` padded with -1 (no destination) to ``num_slices``
+        slices, when that is more than the shard's own."""
+        extra = num_slices * self.cols.shape[1] - self.row_map.size
+        if extra <= 0:
+            return self.row_map
+        return np.concatenate([self.row_map,
+                               np.full(extra, -1, dtype=np.int32)])
+
+    def _slots(self):
+        """Row, lane and virtual row of every edge slot, in CSR order
+        (destination, then the edge's place in its row)."""
+        r_idx, c_idx = np.nonzero(self.cols >= 0)
+        s = np.searchsorted(self.slice_ptr, r_idx, side="right") - 1
+        vrow = s * self.cols.shape[1] + c_idx
+        # a destination's wrapped virtual rows keep their order in the
+        # stable longest-first sort, the full ones ahead of the remainder
+        order = np.lexsort((r_idx, vrow, self.row_map[vrow]))
+        return r_idx[order], c_idx[order], vrow[order]
+
+    def edges(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(local destination int64, source int64, value float32) of every
+        edge in CSR order — the inverse of :func:`csr_to_ell`."""
+        r_idx, c_idx, vrow = self._slots()
+        return (self.row_map[vrow].astype(np.int64),
+                self.cols[r_idx, c_idx].astype(np.int64),
+                self.vals_f32()[r_idx, c_idx].astype(np.float32))
+
+    def neighbors(self, local: int) -> np.ndarray:
+        """Sources of destination row ``local``, in CSR order."""
+        C = self.cols.shape[1]
+        out = []
+        for v in np.flatnonzero(self.row_map == local):
+            s, lane = divmod(int(v), C)
+            col = self.cols[self.slice_ptr[s]: self.slice_ptr[s + 1], lane]
+            out.append(col[col >= 0])
+        return np.concatenate(out) if out else np.zeros(0, np.int32)
 
     def source_vertices(self) -> np.ndarray:
         c = self.cols[self.cols >= 0]
@@ -231,67 +303,86 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-def _bucket_pow2(x: int, floor: int) -> int:
-    """Round up to a power of two (>= floor): shards share few distinct ELL
-    shapes, so the jitted shard step compiles once per bucket, not per shard."""
-    n = max(x, floor)
-    return 1 << (n - 1).bit_length()
-
-
 def _bucket_quarter_pow2(x: int, floor: int) -> int:
     """Round up to a quarter-power-of-two bucket (…, 1024, 1280, 1536, 1792,
     2048, …): ≤4 shapes per octave keeps jit compiles bounded while wasting
-    ≤25% rows (vs ≤100% for pure pow2)."""
+    ≤25% (vs ≤100% for pure pow2)."""
     n = max(x, floor)
     p = max(1 << max((n - 1).bit_length() - 2, 0), floor)
     return -(-n // p) * p
 
 
-def csr_to_ell(shard: CSRShard, max_width: int = 512, lane: int = LANE) -> ELLShard:
-    """Re-lay a CSR shard as blocked-ELL with row wrapping.
+def _bucket_rows(x: int) -> int:
+    """Round a shard's ELL row count up to a 1/32-octave step (at least
+    ROW_ALIGN): shards of about ``threshold_edge_num`` edges share one or
+    two shapes, and the padding stays under 1/32 of the slots."""
+    n = max(x, ROW_ALIGN)
+    step = max(1 << max((n - 1).bit_length() - 5, 0), ROW_ALIGN)
+    return -(-n // step) * step
 
-    ``max_width`` caps the ELL width (multiple of ``lane``); rows with degree
-    above it wrap onto multiple ELL rows.  Width targets ~1.5× the mean
-    degree — the row-wrapping absorbs the power-law tail, so sizing for the
-    tail (e.g. p95) would only inflate padding.  ``lane`` is the hardware
-    vector width the layout aligns to (128 on TPU; benches on CPU may pass
-    a smaller value — the layout algebra is identical).
+
+def store_slices(shard_meta) -> int:
+    """The slice count a shard step's row map is padded to: the most of any
+    shard in the store (0 where the metadata lacks ``slices``), so that a
+    store's shards differ in shape by their row count alone and compile
+    once per row count."""
+    return max((int(m.get("slices", 0)) for m in shard_meta), default=0)
+
+
+def segment_rows(intervals) -> int:
+    """The destination rows one shard step covers: the longest interval,
+    bucketed to a quarter power of two — the static length of every shard's
+    partial array and of the vertex slice it updates."""
+    lengths = np.diff(np.asarray(intervals, dtype=np.int64))
+    return _bucket_quarter_pow2(int(lengths.max(initial=1)), SUBLANE)
+
+
+def csr_to_ell(shard: CSRShard, max_width: int = 512, lane: int = LANE) -> ELLShard:
+    """Re-lay a CSR shard as a sliced ELL (see :class:`ELLShard`).
+
+    ``max_width`` caps a virtual row; longer rows wrap.  ``lane`` is C, the
+    virtual rows a slice holds (the hardware vector width, 128 on TPU).
+    The layout is a function of the shard's own degrees alone: its slots
+    number the edges plus each slice's tail below its longest row (sorting
+    keeps rows of a slice alike) and the rounding of the row count.
     """
+    cap = max(int(max_width), 1)
     deg = np.diff(shard.row)
-    if deg.size == 0 or deg.max() == 0:
-        w = lane
-    else:
-        mean = float(deg[deg > 0].mean()) if (deg > 0).any() else 1.0
-        w = min(_bucket_pow2(max(int(mean * 1.2), 1), lane),
-                _round_up(max_width, lane))
-    # number of ELL rows each CSR row expands into (>=1 so empty rows exist)
-    reps = np.maximum(1, -(-deg // w)).astype(np.int64)
-    r_used = int(reps.sum())
-    R = _bucket_quarter_pow2(r_used, SUBLANE)
-    # vectorized expansion: ELL row -> (csr row, occurrence within that row)
-    row_map = np.zeros(R, dtype=np.int32)
-    row_map[:r_used] = np.repeat(np.arange(shard.num_rows, dtype=np.int32), reps)
-    ell_start = np.concatenate([[0], np.cumsum(reps)])  # first ELL row per CSR row
-    occ = np.arange(r_used, dtype=np.int64) - ell_start[row_map[:r_used]]
-    base = shard.row[row_map[:r_used]] + occ * w  # first edge idx per ELL row
-    idx = base[:, None] + np.arange(w, dtype=np.int64)[None, :]
-    valid = idx < shard.row[row_map[:r_used] + 1][:, None]
-    idx = np.where(valid, idx, 0)
-    cols = np.full((R, w), -1, dtype=np.int32)
-    vals = np.zeros((R, w), dtype=np.float32)
-    if shard.nnz:  # an interval can own zero edges: keep all-sentinel rows
-        cols[:r_used] = np.where(valid, shard.col[idx], -1).astype(np.int32)
-        if shard.val is not None:
-            vals[:r_used] = np.where(valid, shard.val[idx], 0.0).astype(np.float32)
-        else:
-            vals[:r_used] = valid.astype(np.float32)
+    nz = np.flatnonzero(deg)
+    reps = -(-deg[nz] // cap)
+    vdst = np.repeat(nz, reps)
+    first = np.cumsum(reps) - reps              # first virtual row per dst
+    occ = np.arange(vdst.size) - np.repeat(first, reps)
+    vstart = shard.row[vdst] + occ * cap        # first edge of a virtual row
+    vlen = np.minimum(deg[vdst] - occ * cap, cap)
+    order = np.argsort(-vlen, kind="stable")    # longest first, stable
+    vdst, vstart, vlen = vdst[order], vstart[order], vlen[order]
+    V = int(vdst.size)
+    S = _bucket_quarter_pow2(-(-V // lane), 1)
+    depth = np.zeros(S, dtype=np.int64)
+    depth[: -(-V // lane)] = -(-vlen[::lane] // GROUP_ROWS) * GROUP_ROWS
+    slice_ptr = np.concatenate([[0], np.cumsum(depth)]).astype(np.int32)
+    L = _bucket_rows(int(slice_ptr[-1]))
+    # one slot per edge: virtual row v's k-th edge sits at
+    # (slice_ptr[v // C] + k, v % C)
+    v = np.repeat(np.arange(V), vlen)
+    k = np.arange(v.size) - np.repeat(np.cumsum(vlen) - vlen, vlen)
+    slot = (slice_ptr[v // lane] + k) * lane + v % lane
+    edge = vstart[v] + k
+    cols = np.full(L * lane, -1, dtype=np.int32)
+    vals = np.zeros(L * lane, dtype=np.float32)
+    cols[slot] = shard.col[edge]
+    vals[slot] = 1.0 if shard.val is None else shard.val[edge]
+    row_map = np.full(S * lane, -1, dtype=np.int32)
+    row_map[:V] = vdst
     return ELLShard(
         shard_id=shard.shard_id,
         start_vertex=shard.start_vertex,
         end_vertex=shard.end_vertex,
-        cols=cols,
-        vals=vals,
+        cols=cols.reshape(L, lane),
+        vals=vals.reshape(L, lane),
         row_map=row_map,
+        slice_ptr=slice_ptr,
         nnz=shard.nnz,
     )
 
@@ -309,14 +400,6 @@ def quantize_shard(shard: ELLShard, dtype: str) -> ELLShard:
         return shard
     q, scale, zero = quantize_edge_vals(shard.vals, dtype)
     return dataclasses.replace(shard, vals=q, val_scale=scale, val_zero=zero)
-
-
-def bucket_shards(shards: Sequence[ELLShard]) -> dict[tuple[int, int], list[ELLShard]]:
-    """Group shards by (R, W) so each bucket jits once (VSW scan batches)."""
-    buckets: dict[tuple[int, int], list[ELLShard]] = {}
-    for s in shards:
-        buckets.setdefault(s.shape, []).append(s)
-    return buckets
 
 
 def iter_edges(shard: CSRShard) -> Iterator[tuple[int, int, float]]:

@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.core.shards import ELLShard
 from repro.graph.delta import DeltaGraphStore
 
 
@@ -123,7 +124,7 @@ def _compact_packed(store: DeltaGraphStore, base, dirty) -> tuple[int, int]:
         dead += _seg_nbytes(header["vertex_info"][key])
     for p in dirty:
         dead += _seg_nbytes(header["blooms"][p]["bits"])
-        for key in ("cols", "vals", "row_map"):
+        for key in ELLShard.ARRAYS:
             dead += _seg_nbytes(header["shards"][p][key])
 
     with open(base.path, "r+b") as f:
@@ -142,9 +143,8 @@ def _compact_packed(store: DeltaGraphStore, base, dirty) -> tuple[int, int]:
                 "start": int(s.start_vertex), "end": int(s.end_vertex),
                 "nnz": int(s.nnz), "nbytes": len(store._blobs[p]),
                 "val_scale": float(s.val_scale), "val_zero": float(s.val_zero),
-                "cols": _write_segment(f, s.cols),
-                "vals": _write_segment(f, s.vals),
-                "row_map": _write_segment(f, s.row_map)}
+                **{k: _write_segment(f, getattr(s, k))
+                   for k in ELLShard.ARRAYS}}
         header["properties"] = _json_ready(store._prop)
         blob = json.dumps(header, sort_keys=True).encode()
         hdr_off = f.tell()

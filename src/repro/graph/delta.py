@@ -11,7 +11,7 @@ views behind the exact same ``read_shard`` protocol:
     replaces ``mtime_ns`` as the graph-identity/invalidation key) and stamps
     the touched shards with that epoch, so the cache and serve memo layers
     can invalidate *only* what changed.
-  * Merging is **eager**: the dirty shard is re-laid out (CSR → blocked-ELL
+  * Merging is **eager**: the dirty shard is re-laid out (CSR → sliced ELL
     with the base store's layout parameters) at commit time, so
     ``properties`` (shard meta, ``num_edges``), degree arrays, Bloom
     filters, and canonical disk-byte accounting are consistent the moment
@@ -88,21 +88,6 @@ def _as_edge_arrays(edges, weighted_default: float = 1.0):
     if not (src.size == dst.size == val.size):
         raise ValueError("edge arrays must have matching lengths")
     return src, dst, val
-
-
-def _ell_to_csr_triples(shard: ELLShard):
-    """Decode a blocked-ELL shard back to CSR-ordered (local_dst, src, val).
-
-    ``np.nonzero`` walks the [R, W] mask in C order — increasing ELL row,
-    then column — which is exactly the original CSR edge order (wrapped rows
-    of one destination are consecutive, padding rows are all-sentinel).
-    """
-    mask = shard.cols >= 0
-    r_idx, c_idx = np.nonzero(mask)
-    local = shard.row_map[r_idx].astype(np.int64)
-    # vals_f32 dequantizes int8/float16 edge values (float32 passes through)
-    return local, shard.cols[r_idx, c_idx].astype(np.int64), \
-        shard.vals_f32()[r_idx, c_idx].astype(np.float32)
 
 
 class DeltaGraphStore(ShardSourceBase):
@@ -324,7 +309,7 @@ class DeltaGraphStore(ShardSourceBase):
         cur = self._merged.get(p)
         if cur is None:
             cur = self.base.read_shard(p)
-        local, srcs, vals = _ell_to_csr_triples(cur)
+        local, srcs, vals = cur.edges()
         start = cur.start_vertex
         base_keys = (local + start) * n + srcs
 
@@ -376,6 +361,7 @@ class DeltaGraphStore(ShardSourceBase):
         np.add.at(self._out_deg, edit_s, edge_delta)
         meta = self._prop["shards"][p]
         meta["rows"], meta["width"] = (int(x) for x in merged.shape)
+        meta["slices"] = merged.num_slices
         meta["nnz"] = int(merged.nnz)
         base_bloom = self._blooms.get(p) or self.base.read_bloom(p)
         self._merged[p] = merged

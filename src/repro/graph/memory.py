@@ -29,9 +29,8 @@ def _materialized(shard: ELLShard) -> ELLShard:
     disk-backed (pages droppable under pressure, mmap pinned forever)."""
     if shard.cols.flags.writeable:
         return shard  # already owned (npz / direct construction)
-    return dataclasses.replace(shard, cols=np.array(shard.cols),
-                               vals=np.array(shard.vals),
-                               row_map=np.array(shard.row_map))
+    return dataclasses.replace(shard, **{k: np.array(getattr(shard, k))
+                                         for k in ELLShard.ARRAYS})
 
 
 class MemoryGraphStore(ShardSourceBase):

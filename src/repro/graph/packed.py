@@ -3,9 +3,9 @@
 The npz-per-shard directory pays a zip-parse plus an array copy on every
 shard miss.  The packed format removes both: all arrays live as raw
 little-endian segments inside ONE file, 64-byte aligned, described by a JSON
-header — ``read_shard`` returns ``ELLShard`` whose cols/vals/row_map are
-**views into the shared mmap** (no parse, no copy; the OS pages data in on
-first touch, which the ShardPipeline moves off the critical path).
+header — ``read_shard`` returns ``ELLShard`` whose arrays are **views into
+the shared mmap** (no parse, no copy; the OS pages data in on first touch,
+which the ShardPipeline moves off the critical path).
 
 File layout::
 
@@ -17,7 +17,7 @@ File layout::
                  properties      — carried verbatim from the source
                  vertex_info     — segment refs for in/out degree
                  blooms[p]       — segment ref + num_bits/num_hashes
-                 shards[p]       — segment refs for cols/vals/row_map,
+                 shards[p]       — segment refs for the ELLShard.ARRAYS,
                                    start/end/nnz, canonical nbytes
 
 ``nbytes`` per shard is the **canonical npz-blob size recorded at pack
@@ -116,9 +116,8 @@ def _write_packed(source: ShardSource, tmp: Path, header: dict) -> None:
                 "start": int(s.start_vertex), "end": int(s.end_vertex),
                 "nnz": int(s.nnz), "nbytes": int(source.shard_nbytes(p)),
                 "val_scale": float(s.val_scale), "val_zero": float(s.val_zero),
-                "cols": _write_segment(f, s.cols),
-                "vals": _write_segment(f, s.vals),
-                "row_map": _write_segment(f, s.row_map),
+                **{k: _write_segment(f, getattr(s, k))
+                   for k in ELLShard.ARRAYS},
             })
         blob = json.dumps(header, sort_keys=True).encode()
         hdr_off = f.tell()
@@ -190,9 +189,7 @@ class PackedGraphStore(ShardSourceBase):
             start_vertex=int(rec["start"]),
             end_vertex=int(rec["end"]),
             nnz=int(rec["nnz"]),
-            cols=self._view(rec["cols"]),
-            vals=self._view(rec["vals"]),
-            row_map=self._view(rec["row_map"]),
+            **{k: self._view(rec[k]) for k in ELLShard.ARRAYS},
             val_scale=float(rec.get("val_scale", 1.0)),
             val_zero=float(rec.get("val_zero", 0.0)),
         )

@@ -4,7 +4,7 @@ Step 1: scan the edge list, count in/out-degrees, compute vertex intervals
         with Algorithm 1 (cost: D|E| read).
 Step 2: re-scan the edge list, append each edge to its owning shard's scratch
         file by destination interval (D|E| read + D|E| write).
-Step 3: per shard, sort by destination, emit CSR -> blocked-ELL, persist, and
+Step 3: per shard, sort by destination, emit CSR -> sliced ELL, persist, and
         build the shard's Bloom filter over source vertices
         (D|E| read + ~D|E| write).
 
@@ -119,7 +119,8 @@ def preprocess_graph(
             ell = quantize_shard(ell, val_dtype)
         store.write_shard(ell)
         store.write_bloom(p, BloomFilter.build(ell.source_vertices(), num_bits=bloom_bits))
-        shard_meta.append({"rows": int(ell.shape[0]), "width": int(ell.shape[1]), "nnz": ell.nnz})
+        shard_meta.append({"rows": int(ell.shape[0]), "width": int(ell.shape[1]),
+                           "slices": ell.num_slices, "nnz": ell.nnz})
         sp.unlink()
     scratch_dir.rmdir()
 

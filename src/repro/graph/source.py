@@ -121,6 +121,7 @@ def pack_shard_npz(shard: ELLShard) -> bytes:
     payload = dict(
         cols=shard.cols,
         row_map=shard.row_map,
+        slice_ptr=shard.slice_ptr,
         meta=np.array([shard.start_vertex, shard.end_vertex, shard.nnz,
                        int(unit)], dtype=np.int64),
     )
@@ -153,6 +154,7 @@ def unpack_shard_npz(shard_id: int, blob: bytes) -> ELLShard:
             cols=cols,
             vals=vals,
             row_map=z["row_map"],
+            slice_ptr=z["slice_ptr"],
             val_scale=scale,
             val_zero=zero,
         )

@@ -5,7 +5,8 @@ Layout of a preprocessed graph directory:
   property.json          — |V|, |E|, P, intervals, weighted, threshold (paper §2.2)
   vertex_info.npz        — in_degree, out_degree arrays
   bloom_<p>.npz          — per-shard Bloom filter over source vertices (§2.4.1)
-  shard_<p>.npz          — blocked-ELL arrays (cols, vals, row_map) + metadata
+  shard_<p>.npz          — sliced-ELL arrays (cols, vals, row_map, slice_ptr)
+                           + metadata
 
 ``GraphStore`` is one implementation of the ``ShardSource`` protocol
 (graph/source.py); the single-file mmap'd ``PackedGraphStore`` and the

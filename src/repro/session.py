@@ -81,6 +81,7 @@ from repro.core.apps import (BatchedVertexProgram, DriverProgram,
 from repro.core.cache import CompressedShardCache, PartitionedShardCache
 from repro.core.engine import (BatchRunResult, EngineConfig, IterationStats,
                                RunResult, VSWEngine, _store_epoch)
+from repro.core.shards import segment_rows
 from repro.graph.source import ShardSource, path_mtime_ns
 from repro.graph.storage import GraphStore
 
@@ -226,10 +227,9 @@ class GraphSession:
         # shared vertex metadata: read from disk exactly once per session
         self.in_deg, self.out_deg = store.read_vertex_info()
         self.blooms = store.read_all_blooms()
-        shard_meta = store.properties["shards"]
-        self.max_rows = max((m["rows"] for m in shard_meta), default=8)
         self.n = store.num_vertices
-        self.n_pad = self.n + self.max_rows
+        # room for the last interval's shard step (engine.segments)
+        self.n_pad = self.n + segment_rows(store.intervals)
         # device-resident padded out-degrees, shared by every engine
         self.out_deg_dev = jnp.asarray(
             np.pad(self.out_deg, (0, self.n_pad - self.n)).astype(np.float32))
@@ -560,9 +560,6 @@ class GraphSession:
         if cur == prev:
             return
         self.in_deg, self.out_deg = self.store.read_vertex_info()
-        shard_meta = self.store.properties["shards"]
-        self.max_rows = max((m["rows"] for m in shard_meta), default=8)
-        self.n_pad = max(self.n_pad, self.n + self.max_rows)  # grow-only
         self.out_deg_dev = jnp.asarray(
             np.pad(self.out_deg, (0, self.n_pad - self.n)).astype(np.float32))
         shard_epoch = getattr(self.store, "shard_epoch", None)

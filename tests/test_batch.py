@@ -14,6 +14,7 @@ import pytest
 import jax.numpy as jnp
 
 from tests._hypo import given, settings, st
+from tests._layouts import random_layout
 
 from repro.core.apps import get_app
 from repro.core.engine import BatchRunResult
@@ -31,13 +32,10 @@ from repro.session import GraphSession
 @pytest.mark.parametrize("semiring", sorted(SEMIRINGS))
 def test_batched_spmv_paths_agree_all_semirings(semiring):
     rng = np.random.default_rng(42)
-    n, R, W, K = 257, 64, 256, 7
-    cols = rng.integers(-1, n, size=(R, W)).astype(np.int32)
-    vals = rng.random((R, W)).astype(np.float32)
+    n, rows, K = 257, 120, 7
+    layout = random_layout(rng, n, rows, 3000)
     x = (rng.random((n, K)) + 0.1).astype(np.float32)
-    row_map = np.sort(rng.integers(0, R // 2, size=R)).astype(np.int32)
-    args = (jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(row_map), R,
-            semiring)
+    args = tuple(jnp.asarray(a) for a in layout) + (rows, semiring)
     pallas = ell_spmv_batch(jnp.asarray(x), *args, use_pallas=True)
     jnp_path = ell_spmv_batch(jnp.asarray(x), *args, use_pallas=False)
     oracle = ref.ell_spmv_batch_ref(jnp.asarray(x), *args)

@@ -24,8 +24,7 @@ import numpy as np
 import pytest
 
 from repro.graph.compact import compact
-from repro.graph.delta import (DeltaBudgetError, DeltaGraphStore,
-                               _ell_to_csr_triples)
+from repro.graph.delta import DeltaBudgetError, DeltaGraphStore
 from repro.graph.preprocess import preprocess_graph
 from repro.graph.source import ConcurrentMutationError, graph_token
 from repro.graph.storage import GraphStore, write_edge_list
@@ -147,7 +146,7 @@ def test_noop_upsert_preserves_content_and_size(graphs):
     base, _, (src, dst, w), _ = graphs
     store = DeltaGraphStore(GraphStore(base))
     before = store.read_shard(0)
-    edges_before = sorted(zip(*_ell_to_csr_triples(before)))
+    edges_before = sorted(zip(*before.edges()))
     nbytes_before = store.shard_nbytes(0)
     iv = store.intervals
     sel = (dst >= iv[0]) & (dst < iv[1])
@@ -155,7 +154,7 @@ def test_noop_upsert_preserves_content_and_size(graphs):
     store.apply(inserts=[(int(src[i]), int(dst[i]), float(w[i]))])
     assert store.dirty_shards() == [0]
     after = store.read_shard(0)
-    assert sorted(zip(*_ell_to_csr_triples(after))) == edges_before
+    assert sorted(zip(*after.edges())) == edges_before
     assert after.shape == before.shape
     assert store.shard_nbytes(0) == nbytes_before
 
@@ -167,7 +166,7 @@ def test_upsert_collapses_and_updates_weight(tmp_path):
     store = DeltaGraphStore(GraphStore(g))
     store.apply(updates=[(0, 1, 5.0)])  # weight upsert, no new edge
     assert store.num_edges == 3
-    _, s, v = _ell_to_csr_triples(store.read_shard(0))
+    _, s, v = store.read_shard(0).edges()
     assert v[s == 0] == pytest.approx([5.0])
     in_deg, out_deg = store.read_vertex_info()
     assert in_deg.tolist() == [1, 1, 1] and out_deg.tolist() == [1, 1, 1]
@@ -299,6 +298,10 @@ def test_compaction_roundtrip(graphs, backend, tmp_path):
                       selective_threshold=THRESH) as reopened:
         assert np.array_equal(reopened.run("sssp", source=0).values, want)
         assert reopened.store.num_edges == ref_edges
+        # the shard metadata follows the re-merged shards
+        assert [m["slices"] for m in reopened.store.properties["shards"]] \
+            == [reopened.store.read_shard(p).num_slices
+                for p in range(reopened.store.num_shards)]
         if backend == "npz":
             # disk-byte accounting matches a fresh pack of the merged graph
             got = [reopened.store.shard_nbytes(p)
@@ -489,7 +492,7 @@ def _store_edge_dict(store):
     out = {}
     for p in range(store.num_shards):
         shard = store.read_shard(p)
-        local, s, v = _ell_to_csr_triples(shard)
+        local, s, v = shard.edges()
         for li, si, vi in zip(local + shard.start_vertex, s, v):
             out[(int(si), int(li))] = float(np.float32(vi))
     return out

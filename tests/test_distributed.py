@@ -158,29 +158,33 @@ def test_spmv_2d_partition():
     out = run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import Mesh
-        from repro.core.distributed import spmv_2d
+        from repro.core.distributed import spmv_2d, stack_layouts
         from repro.kernels.spmv import ref
+        from tests._layouts import random_layout
 
         mesh = jax.make_mesh((2, 2), ('data', 'model'),
                              axis_types=(jax.sharding.AxisType.Auto,)*2)
         rng = np.random.default_rng(0)
-        D, S, R, W, nloc = 2, 2, 16, 128, 64
+        D, S, R, nloc = 2, 2, 16, 64
         n = S * nloc
         # cols are LOCAL source indices into each device's x block
-        cols = rng.integers(-1, nloc, size=(D, S, R, W)).astype(np.int32)
-        vals = rng.random((D, S, R, W)).astype(np.float32)
-        row_map = np.sort(rng.integers(0, R, size=(D, S, R)), -1).astype(np.int32)
+        tiles = [[random_layout(rng, nloc, R, 200) for s in range(S)]
+                 for d in range(D)]
+        cols, vals, slices, row_map = (
+            a.reshape((D, S) + a.shape[1:])
+            for a in stack_layouts([t for row in tiles for t in row]))
         x = rng.random(n).astype(np.float32)
         out = spmv_2d(jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
-                      jnp.asarray(row_map), 'plus_times', mesh)
+                      jnp.asarray(slices), jnp.asarray(row_map), R,
+                      'plus_times', mesh)
         # oracle: per dst-block, sum over src blocks of local spmv
         want = np.zeros((D, R), np.float32)
         for d in range(D):
             for s in range(S):
                 xb = x[s*nloc:(s+1)*nloc]
-                seg = ref.ell_spmv_ref(jnp.asarray(xb), jnp.asarray(cols[d, s]),
-                                       jnp.asarray(vals[d, s]),
-                                       jnp.asarray(row_map[d, s]), R, 'plus_times')
+                seg = ref.ell_spmv_ref(jnp.asarray(xb),
+                                       *(jnp.asarray(a) for a in tiles[d][s]),
+                                       R, 'plus_times')
                 want[d] += np.asarray(seg)
         got = np.asarray(out).reshape(D, R)
         np.testing.assert_allclose(got, want, rtol=1e-5)
@@ -195,27 +199,31 @@ def test_spmv_2d_min_semiring():
     of per-block single-device SpMVs EXACTLY (min never rounds)."""
     out = run_with_devices("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core.distributed import spmv_2d
+        from repro.core.distributed import spmv_2d, stack_layouts
         from repro.kernels.spmv import ref
+        from tests._layouts import random_layout
 
         mesh = jax.make_mesh((2, 2), ('data', 'model'),
                              axis_types=(jax.sharding.AxisType.Auto,)*2)
         rng = np.random.default_rng(1)
-        D, S, R, W, nloc = 2, 2, 16, 128, 48  # nloc deliberately unaligned
+        D, S, R, nloc = 2, 2, 16, 48  # nloc deliberately unaligned
         n = S * nloc
-        cols = rng.integers(-1, nloc, size=(D, S, R, W)).astype(np.int32)
-        vals = rng.random((D, S, R, W)).astype(np.float32)
-        row_map = np.sort(rng.integers(0, R, size=(D, S, R)), -1).astype(np.int32)
+        tiles = [[random_layout(rng, nloc, R, 150) for s in range(S)]
+                 for d in range(D)]
+        cols, vals, slices, row_map = (
+            a.reshape((D, S) + a.shape[1:])
+            for a in stack_layouts([t for row in tiles for t in row]))
         x = rng.random(n).astype(np.float32)
         out = spmv_2d(jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
-                      jnp.asarray(row_map), 'min_plus', mesh)
+                      jnp.asarray(slices), jnp.asarray(row_map), R,
+                      'min_plus', mesh)
         want = np.full((D, R), np.inf, np.float32)
         for d in range(D):
             for s in range(S):
                 xb = x[s*nloc:(s+1)*nloc]
-                seg = ref.ell_spmv_ref(jnp.asarray(xb), jnp.asarray(cols[d, s]),
-                                       jnp.asarray(vals[d, s]),
-                                       jnp.asarray(row_map[d, s]), R, 'min_plus')
+                seg = ref.ell_spmv_ref(jnp.asarray(xb),
+                                       *(jnp.asarray(a) for a in tiles[d][s]),
+                                       R, 'min_plus')
                 want[d] = np.minimum(want[d], np.asarray(seg))
         got = np.asarray(out).reshape(D, R)
         assert np.array_equal(got, want), np.abs(got - want).max()

@@ -5,48 +5,77 @@ import numpy as np
 import pytest
 
 from tests._hypo import given, settings, st
+from tests._layouts import random_layout, random_shard
 
 from repro.core.semiring import SEMIRINGS
+from repro.core.shards import (GROUP_ROWS, ROW_ALIGN, dequantize_edge_vals,
+                               quantize_edge_vals)
 from repro.kernels.spmv import ops, ref, spmv
 from repro.kernels.spmv.ops import (describe_dispatch, ell_fold,
                                     ell_gather_fold, ell_spmv, ell_spmv_batch)
 
 SEMIS = list(SEMIRINGS)
-SHAPES = [(8, 128), (64, 256), (256, 128), (512, 640)]
+# (destination rows, edges) of one shard: one slice, several slices of
+# unequal depth, hubs past the cap, a wide interval
+SHAPES = [(16, 40), (300, 3000), (64, 5000), (2000, 12000)]
 DTYPES = [np.float32, np.dtype("bfloat16")]
 
 
-def _make(rng, n, R, W, dtype):
-    cols = rng.integers(-1, n, size=(R, W)).astype(np.int32)
-    vals = rng.random((R, W)).astype(np.float32).astype(dtype)
-    x = (rng.random(n).astype(np.float32) + 0.1).astype(dtype)
-    row_map = np.sort(rng.integers(0, max(R // 2, 1), size=R)).astype(np.int32)
-    return cols, vals, x, row_map
+def _args(layout, x=None):
+    return tuple(jnp.asarray(a) for a in ((x,) if x is not None else ())
+                 + tuple(layout))
+
+
+def _oracle(layout, x, rows, semiring):
+    """``ref.ell_spmv_ref`` on ``layout``: sums in float64, where a float32
+    sum of thousands of slots in slot order can itself stray 1e-5 from the
+    exact value; min and max in float32, which they never round."""
+    if not SEMIRINGS[semiring].is_plus:
+        return np.asarray(ref.ell_spmv_ref(*_args(layout, x), rows, semiring))
+    cols, vals, slices, row_map = layout
+    with jax.enable_x64(True):
+        return np.asarray(ref.ell_spmv_ref(
+            jnp.asarray(x, jnp.float64), jnp.asarray(cols),
+            jnp.asarray(vals, jnp.float64), jnp.asarray(slices),
+            jnp.asarray(row_map), rows, semiring))
 
 
 @pytest.mark.parametrize("semiring", SEMIS)
 @pytest.mark.parametrize("shape", SHAPES)
 def test_ell_spmv_vs_ref(semiring, shape):
-    R, W = shape
-    rng = np.random.default_rng(R * W)
-    cols, vals, x, row_map = _make(rng, 1000, R, W, np.float32)
-    out = ell_spmv(jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
-                   jnp.asarray(row_map), R, semiring, use_pallas=True)
-    want = ref.ell_spmv_ref(jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
-                            jnp.asarray(row_map), R, semiring)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6)
+    """The Pallas path against the jnp path, and both against the
+    slot-by-slot oracle: sums within rtol 1e-5 (they re-associate), min and
+    max exactly."""
+    rows, edges = shape
+    rng = np.random.default_rng(rows * edges)
+    layout = random_layout(rng, 1000, rows, edges)
+    x = rng.random(1000).astype(np.float32)
+    out = np.asarray(ell_spmv(*_args(layout, x), rows, semiring,
+                              use_pallas=True))
+    jnp_path = np.asarray(ell_spmv(*_args(layout, x), rows, semiring,
+                                   use_pallas=False))
+    np.testing.assert_allclose(out, jnp_path, rtol=1e-6)
+    want = _oracle(layout, x, rows, semiring)
+    for got in (out, jnp_path):
+        if SEMIRINGS[semiring].is_plus:
+            np.testing.assert_allclose(got, want, rtol=1e-5)
+        else:
+            np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("semiring", SEMIS)
 @pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
 def test_ell_fold_dtypes(semiring, dtype):
     rng = np.random.default_rng(3)
-    cols, vals, x, _ = _make(rng, 300, 64, 256, dtype)
-    xg = x[np.where(cols >= 0, cols, 0)]
+    cols, vals, _, _ = random_layout(rng, 300, 200, 4000)
+    x = (rng.random(300).astype(np.float32) + 0.1).astype(dtype)
+    vals = vals.astype(dtype)
+    xg = x[np.where(cols >= 0, cols, 0)][None]
     out = ell_fold(jnp.asarray(xg), jnp.asarray(vals), jnp.asarray(cols),
                    semiring, use_pallas=True)
-    want = ref.ell_fold_ref(jnp.asarray(xg), jnp.asarray(vals), jnp.asarray(cols),
-                            semiring)
+    want = ref.ell_fold_ref(jnp.asarray(xg), jnp.asarray(vals),
+                            jnp.asarray(cols), semiring)
+    assert out.shape == (1, cols.shape[0] // GROUP_ROWS, cols.shape[1])
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
                                rtol=2e-2 if dtype != np.float32 else 1e-6)
@@ -56,11 +85,13 @@ def test_ell_fold_dtypes(semiring, dtype):
 def test_ell_gather_fold_vs_ref(semiring):
     rng = np.random.default_rng(9)
     VB = 512
-    cols, vals, x, _ = _make(rng, VB, 128, 384, np.float32)
+    cols, vals, _, _ = random_layout(rng, VB, 400, 6000)
+    x = rng.random(VB).astype(np.float32)
     out = ell_gather_fold(jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
                           semiring, use_pallas=True)
-    want = ref.ell_gather_fold_ref(jnp.asarray(x), jnp.asarray(cols),
-                                   jnp.asarray(vals), semiring)
+    xg = x[np.where(cols >= 0, cols, 0)][None]
+    want = ref.ell_fold_ref(jnp.asarray(xg), jnp.asarray(vals),
+                            jnp.asarray(cols), semiring)[0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6)
 
 
@@ -68,79 +99,140 @@ def test_ell_gather_fold_vs_ref(semiring):
 @settings(max_examples=20, deadline=None)
 def test_property_random_small(seed, semiring):
     rng = np.random.default_rng(seed)
-    R = 8 * rng.integers(1, 5)
-    W = 128 * rng.integers(1, 3)
-    cols, vals, x, row_map = _make(rng, int(rng.integers(2, 500)), R, W, np.float32)
-    out = ell_spmv(jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
-                   jnp.asarray(row_map), R, semiring, use_pallas=True)
-    want = ref.ell_spmv_ref(jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
-                            jnp.asarray(row_map), R, semiring)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-5)
+    rows = int(rng.integers(1, 300))
+    n = int(rng.integers(2, 500))
+    layout = random_layout(rng, n, rows, int(rng.integers(0, 3000)),
+                           cap=int(rng.integers(1, 200)))
+    x = rng.random(n).astype(np.float32)
+    out = ell_spmv(*_args(layout, x), rows, semiring, use_pallas=True)
+    want = _oracle(layout, x, rows, semiring)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("semiring", SEMIS)
+def test_padded_row_map_changes_nothing(semiring):
+    """A row map padded with slices that map no row (as staging pads it to
+    the store's slice count) gives the same result, bit for bit."""
+    rng = np.random.default_rng(17)
+    shard = random_shard(rng, 600, 300, 4000)
+    x = rng.random(600).astype(np.float32)
+    rows = 300
+    layout = (shard.cols, shard.vals, shard.group_slices(), shard.row_map)
+    padded = layout[:3] + (shard.staged_row_map(2 * shard.num_slices + 3),)
+    want = ell_spmv(*_args(layout, x), rows, semiring, use_pallas=True)
+    got = ell_spmv(*_args(padded, x), rows, semiring, use_pallas=True)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_all_masked_rows_give_identity():
+    """A shard without edges (every slot a sentinel, every virtual row
+    padding) gives the identity on every destination row."""
     for semiring in SEMIS:
         sem = SEMIRINGS[semiring]
-        cols = jnp.full((8, 128), -1, jnp.int32)
-        vals = jnp.zeros((8, 128), jnp.float32)
-        x = jnp.ones((16,), jnp.float32)
-        out = ell_spmv(x, cols, vals, jnp.zeros((8,), jnp.int32), 8, semiring,
+        layout = random_layout(np.random.default_rng(0), 16, 8, 0)
+        out = ell_spmv(*_args(layout, np.ones(16, np.float32)), 8, semiring,
                        use_pallas=True)
-        assert np.asarray(out)[1:].tolist() == [sem.identity] * 7
+        assert np.asarray(out).tolist() == [sem.identity] * 8
 
 
 # ---------------------------------------------------------------------------
-# batched fold kernel (column-major [K, R, W] gather layout) + dispatch
+# the fold against the oracle: semirings x K x edge dtypes x shard kinds
+# ---------------------------------------------------------------------------
+FOLD_SEMIS = ["plus_times", "min_plus", "max_src"]
+EDGE_DTYPES = ["float32", "int8", "float16"]
+SHARD_KINDS = {
+    # skewed in-degrees over 300 rows, some past the cap
+    "skewed": lambda rng: random_shard(rng, 700, 300, 5000),
+    # an interval of 512 rows of which only the first 40 have in-edges
+    "empty_interval": lambda rng: random_shard(
+        rng, 700, 512, 900, dst=rng.integers(0, 40, 900)),
+    # every edge into one hub: one destination, wrapped at the cap
+    "single_hub": lambda rng: random_shard(
+        rng, 700, 64, 3000, cap=128, dst=np.full(3000, 17)),
+}
+
+
+@pytest.mark.parametrize("kind", SHARD_KINDS)
+@pytest.mark.parametrize("edge_dtype", EDGE_DTYPES)
+@pytest.mark.parametrize("k", [1, 16])
+@pytest.mark.parametrize("semiring", FOLD_SEMIS)
+def test_fold_vs_ref(semiring, k, edge_dtype, kind):
+    """The Pallas fold (interpreted) through ``ell_spmv``/``ell_spmv_batch``
+    against the slot-by-slot oracle on dequantized values: every
+    destination of every column, in-degree-0 rows included."""
+    rng = np.random.default_rng(11)
+    shard = SHARD_KINDS[kind](rng)
+    rows = shard.end_vertex - shard.start_vertex
+    q, scale, zero = quantize_edge_vals(shard.vals, edge_dtype)
+    qp = jnp.asarray([scale, zero], jnp.float32)
+    vdq = dequantize_edge_vals(q, scale, zero)
+    x = rng.random((700, k)).astype(np.float32)
+    layout = (shard.cols, q, shard.group_slices(), shard.row_map)
+    oracle = (shard.cols, vdq, shard.group_slices(), shard.row_map)
+    if k == 1:
+        got = ell_spmv(*_args(layout, x[:, 0]), rows, semiring,
+                       use_pallas=True, qparams=qp)[:, None]
+    else:
+        got = ell_spmv_batch(*_args(layout, x), rows, semiring,
+                             use_pallas=True, qparams=qp)
+    want = ref.ell_spmv_batch_ref(*_args(oracle, x), rows, semiring)
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == (rows, k)
+    # rows without in-edges hold the identity in every column
+    has_edge = np.zeros(rows, bool)
+    has_edge[shard.row_map[shard.row_map >= 0]] = True
+    assert (got[~has_edge] == SEMIRINGS[semiring].identity).all()
+    # min/max never round: within 1 ulp (a dequantize-multiply contracted
+    # into the add); sums re-associate
+    np.testing.assert_allclose(got, want, rtol=1e-5 if semiring ==
+                               "plus_times" else 3e-7)
+
+
+# ---------------------------------------------------------------------------
+# batched fold (column-major [K, L, C] gather layout) + dispatch
 # ---------------------------------------------------------------------------
 EXACT_SEMIS = ["min_plus", "max_src"]  # no float re-association: bitwise
 
 
-def _make_batch(rng, n, R, W, K):
-    cols = rng.integers(-1, n, size=(R, W)).astype(np.int32)
-    vals = rng.random((R, W)).astype(np.float32)
-    x = rng.random((n, K)).astype(np.float32)
-    row_map = np.sort(rng.integers(0, max(R // 2, 1), size=R)).astype(np.int32)
-    return cols, vals, x, row_map
-
-
 def _gather_cols_major(x, cols):
-    """[n, K] sources gathered column-major: [K, R, W]."""
+    """[n, K] sources gathered column-major: [K, L, C]."""
     return np.ascontiguousarray(x.T[:, np.where(cols >= 0, cols, 0)])
 
 
 @pytest.mark.parametrize("semiring", SEMIS)
 @pytest.mark.parametrize("k", [1, 5])
 def test_batch_columns_match_solo_fold_bitwise(semiring, k):
-    """Each column of the batched kernel is the single-column fold kernel's
-    result, bit for bit, on every semiring: both reduce the same [tr, tw]
-    tiles in the same order (run_batch columns equal solo runs)."""
+    """Each column of a K-column fold is the single-column fold's result,
+    bit for bit, on every semiring: every lane and column reduces its own
+    row groups (run_batch columns equal solo runs)."""
     rng = np.random.default_rng(42 + k)
-    n, R, W = 700, 64, 256
-    cols, vals, x, _ = _make_batch(rng, n, R, W, k)
-    out = np.asarray(spmv.ell_fold_batch_pallas(
+    n = 700
+    cols, vals, _, _ = random_layout(rng, n, 300, 5000)
+    x = rng.random((n, k)).astype(np.float32)
+    out = np.asarray(spmv.ell_fold_pallas(
         jnp.asarray(_gather_cols_major(x, cols)), jnp.asarray(vals),
         jnp.asarray(cols), semiring, interpret=True))
-    assert out.shape == (R, k)
+    assert out.shape == (k, cols.shape[0] // GROUP_ROWS, cols.shape[1])
     for c in range(k):
-        xg = x[np.where(cols >= 0, cols, 0), c]
-        solo = spmv.ell_fold_pallas(jnp.asarray(xg), jnp.asarray(vals),
-                                    jnp.asarray(cols), semiring,
-                                    interpret=True)
-        assert np.array_equal(out[:, c], np.asarray(solo)[:, 0])
+        solo = spmv.ell_fold_pallas(
+            jnp.asarray(_gather_cols_major(x[:, c:c + 1], cols)),
+            jnp.asarray(vals), jnp.asarray(cols), semiring, interpret=True)
+        assert np.array_equal(out[c], np.asarray(solo)[0])
 
 
 @pytest.mark.parametrize("semiring", SEMIS)
 def test_batch_native_layout_vs_ref(semiring):
-    """ell_fold_batch_pallas consumes the column-major [K, R, W] gather
-    layout natively and matches the oracle."""
+    """ell_fold_pallas consumes the column-major [K, L, C] gather layout
+    natively and matches the oracle."""
     rng = np.random.default_rng(5)
-    cols, vals, x, _ = _make_batch(rng, 400, 72, 384, 6)
+    cols, vals, _, _ = random_layout(rng, 400, 200, 6000)
+    x = rng.random((400, 6)).astype(np.float32)
     xg = jnp.asarray(_gather_cols_major(x, cols))
-    out = spmv.ell_fold_batch_pallas(xg, jnp.asarray(vals), jnp.asarray(cols),
-                                     semiring, interpret=True)
-    want = ref.ell_fold_batch_ref(xg, jnp.asarray(vals), jnp.asarray(cols),
-                                  semiring)
-    assert out.shape == (72, 6)
+    out = spmv.ell_fold_pallas(xg, jnp.asarray(vals), jnp.asarray(cols),
+                               semiring, interpret=True)
+    want = ref.ell_fold_ref(xg, jnp.asarray(vals), jnp.asarray(cols),
+                            semiring)
+    assert out.shape == (6, cols.shape[0] // GROUP_ROWS, cols.shape[1])
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6)
 
 
@@ -161,15 +253,15 @@ def _count_gathers_outside_pallas(jaxpr) -> int:
 
 
 def test_batch_pallas_path_gathers_once():
-    """The Pallas batch path gathers the [K, R, W] sources in ONE XLA
+    """The Pallas batch path gathers the [K, L, C] sources in ONE XLA
     gather (no per-column gathers, no second gather for the layout)."""
     rng = np.random.default_rng(0)
-    n, R, W, k = 600, 16, 128, 3
-    cols, vals, x, row_map = _make_batch(rng, n, R, W, k)
-    args = (jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
-            jnp.asarray(row_map))
+    n, rows, k = 600, 50, 3
+    layout = random_layout(rng, n, rows, 800)
+    x = rng.random((n, k)).astype(np.float32)
     jaxpr = jax.make_jaxpr(
-        lambda *a: ell_spmv_batch(*a, R, "min_plus", use_pallas=True))(*args)
+        lambda *a: ell_spmv_batch(*a, rows, "min_plus", use_pallas=True))(
+            *_args(layout, x))
     assert _count_gathers_outside_pallas(jaxpr.jaxpr) == 1
 
 
@@ -181,7 +273,7 @@ def test_dispatch_table_cpu():
     # referee path, batched falls back to jnp
     assert describe_dispatch("auto", k=1) == "pallas:interpret:gather+fold"
     assert describe_dispatch("auto", k=16) == "jnp"
-    # forced Pallas: the fold kernels for every K
+    # forced Pallas: the fold kernel for every K
     assert describe_dispatch(True, k=1) == "pallas:interpret:gather+fold"
     assert describe_dispatch(True, k=16) == "pallas:interpret:gather+fold"
 
@@ -196,10 +288,10 @@ def test_resolve_no_dead_interpret_flag():
 
 
 def test_compiled_dispatch_is_tpu_only(monkeypatch):
-    """TPU compiles the fold kernels for every K under 'auto'.  GPU backends
-    run grid programs in parallel, so the kernels' sequential W-axis
-    accumulation must never compile there: 'auto' demotes to the
-    fully-XLA-compiled jnp path, forced True keeps the interpret referee."""
+    """TPU compiles the fold kernel for every K under 'auto'.  The kernel
+    is written and tested for Mosaic TPU alone: on GPU backends 'auto'
+    demotes to the fully-XLA-compiled jnp path, forced True keeps the
+    interpret referee."""
     assert ops._COMPILED_BACKENDS == ("tpu",)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert ops._resolve("auto") == (True, False)
@@ -215,34 +307,18 @@ def test_compiled_dispatch_is_tpu_only(monkeypatch):
         assert describe_dispatch(True, k=16) == "pallas:interpret:gather+fold"
 
 
-def test_vmem_block_bytes_padding():
-    """VMEM tiles the two minor dims to (8 sublane, 128 lane): a K=1 column
-    occupies 128 lanes per row, which the unpadded n*k*itemsize model
-    under-counted by 128x."""
-    assert spmv.vmem_block_bytes((1000, 1), 4) == 1000 * 128 * 4
-    assert spmv.vmem_block_bytes((32, 100, 16), 4) == 32 * 104 * 128 * 4
-    # aligned shapes pad to themselves
-    assert spmv.vmem_block_bytes((256, 8, 128), 4) == 256 * 8 * 128 * 4
-
-
 def test_batch_tiles_keep_solo_width():
-    """The batched kernel never shrinks the width tile: each column then
-    reduces over exactly the lanes ell_fold_pallas reduces over."""
-    for (R, W, K) in [(512, 1024, 1), (80_000, 512, 16), (80_000, 128, 256),
-                      (8, 128, 3)]:
-        _tk, _tr, tw = spmv._batch_tiles(R, W, K, 4)
-        assert tw == min(spmv.DEFAULT_TW, W)
-
-
-def test_batch_tiles_respect_padded_budget():
-    """Auto-shrunk [tk, tr, tw] tiles fit TILE_BYTES_BUDGET under the padded
-    model (or sit at the floor: one column of MIN_BATCH_TR rows)."""
-    for (R, W, K) in [(512, 1024, 1), (512, 1024, 16), (64, 256, 4),
-                      (80_000, 512, 256)]:
-        tk, tr, tw = spmv._batch_tiles(R, W, K, 4)
-        at_floor = tk == 1 and tr <= min(R, spmv.MIN_BATCH_TR)
-        assert (spmv.vmem_block_bytes((tk, tr, tw), 4)
-                <= spmv.TILE_BYTES_BUDGET) or at_floor
+    """Row tiles never split the lanes or a row group, and divide the
+    array: every block spans all C lanes, holds whole groups of GROUP_ROWS
+    rows and whole (8, 128) output tiles, so no block is partial; a row
+    count off the alignment is refused."""
+    for L in (512, 8704, 2944, ROW_ALIGN, 96 * ROW_ALIGN):
+        tb = spmv._row_tile(L)
+        assert L % tb == 0 and tb % ROW_ALIGN == 0
+        assert (tb // GROUP_ROWS) % 8 == 0
+    assert spmv._row_tile(8704) == 512 and spmv._row_tile(2944) == 128
+    with pytest.raises(ValueError, match="csr_to_ell"):
+        spmv._row_tile(ROW_ALIGN + 8)
 
 
 @pytest.mark.parametrize("semiring", EXACT_SEMIS)
@@ -250,9 +326,9 @@ def test_ops_batch_paths_agree_bitwise(semiring):
     """Public ell_spmv_batch: forced-Pallas, forced-jnp, and auto all agree
     bitwise on exact semirings."""
     rng = np.random.default_rng(17)
-    cols, vals, x, row_map = _make_batch(rng, 500, 32, 128, 4)
-    args = (jnp.asarray(x), jnp.asarray(cols), jnp.asarray(vals),
-            jnp.asarray(row_map), 32, semiring)
+    layout = random_layout(rng, 500, 100, 3000)
+    x = rng.random((500, 4)).astype(np.float32)
+    args = _args(layout, x) + (100, semiring)
     outs = [np.asarray(ell_spmv_batch(*args, use_pallas=up))
             for up in (True, False, "auto")]
     assert np.array_equal(outs[0], outs[1])
@@ -260,10 +336,10 @@ def test_ops_batch_paths_agree_bitwise(semiring):
 
 
 def test_segment_combine_batch_drops_out_of_range_ids():
-    """Flattened batched combine: an id at num_segments (the sharded
-    engine's padded rows) is dropped, never spilled into the next column."""
-    partials = jnp.asarray(np.arange(12, dtype=np.float32).reshape(4, 3))
-    row_map = jnp.asarray(np.array([0, 1, 1, 2], np.int32))
+    """Flattened batched combine: an id at num_segments or a padding
+    virtual row's -1 is dropped, never spilled into another column."""
+    partials = jnp.asarray(np.arange(15, dtype=np.float32).reshape(5, 3))
+    row_map = jnp.asarray(np.array([0, 1, 1, 2, -1], np.int32))
     out = np.asarray(ref.segment_combine_batch(partials, row_map, 2,
                                                "plus_times"))
     want = np.array([[0, 1, 2], [3 + 6, 4 + 7, 5 + 8]], np.float32)

@@ -24,7 +24,8 @@ def _fake_shard(p: int) -> ELLShard:
     cols = np.full((8, 4), -1, dtype=np.int32)
     return ELLShard(shard_id=p, start_vertex=0, end_vertex=8, nnz=0,
                     cols=cols, vals=np.zeros((8, 4), np.float32),
-                    row_map=np.zeros(8, np.int32))
+                    row_map=np.full(4, -1, np.int32),
+                    slice_ptr=np.zeros(2, np.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,7 @@ from repro.core.shards import (ELLShard, dequantize_edge_vals,
                                quantize_edge_vals, quantize_shard)
 from repro.kernels.spmv import ref, spmv
 from repro.kernels.spmv.ops import ell_spmv, ell_spmv_batch
+from tests._layouts import random_layout
 
 REPO = Path(__file__).resolve().parent.parent
 EXACT_SEMIS = ["min_plus", "max_src"]
@@ -76,8 +77,8 @@ def test_quantize_shard_fields_and_accounting():
     rng = np.random.default_rng(2)
     cols = rng.integers(-1, 100, (16, 128)).astype(np.int32)
     vals = rng.random((16, 128), np.float32)
-    s = ELLShard(0, 0, 10, cols, vals, np.arange(16, dtype=np.int32),
-                 int((cols >= 0).sum()))
+    s = ELLShard(0, 0, 10, cols, vals, np.arange(128, dtype=np.int32),
+                 np.array([0, 16], np.int32), int((cols >= 0).sum()))
     q = quantize_shard(s, "int8")
     assert q.quantized and q.vals.dtype == np.int8
     # decoded-byte accounting shrinks with the stored dtype (cache budgets
@@ -92,12 +93,15 @@ def test_quantize_shard_fields_and_accounting():
 # ---------------------------------------------------------------------------
 # dequant-in-kernel vs oracles
 # ---------------------------------------------------------------------------
-def _problem(rng, n=700, R=64, W=256, K=4):
-    cols = rng.integers(-1, n, size=(R, W)).astype(np.int32)
-    vals = (rng.random((R, W), np.float32) * 4 - 1).astype(np.float32)
-    x = rng.random((n, K)).astype(np.float32)
-    row_map = np.sort(rng.integers(0, R // 2, size=R)).astype(np.int32)
-    return cols, vals, x, row_map
+def _problem(rng, n=700, rows=200, edges=5000, K=4):
+    cols, _, slices, row_map = random_layout(rng, n, rows, edges)
+    vals = np.where(cols >= 0, rng.random(cols.shape, np.float32) * 4 - 1,
+                    0.0).astype(np.float32)
+    # sources in [4, 5): every edge's w + x stays in [3, 8), away from 0,
+    # so one rounding of the dequantize-multiply (an FMA contraction) is
+    # within 3e-7 of the result
+    x = (rng.random((n, K)) + 4).astype(np.float32)
+    return cols, vals, x, (jnp.asarray(slices), jnp.asarray(row_map), rows)
 
 
 @pytest.mark.parametrize("semiring", EXACT_SEMIS)
@@ -107,19 +111,18 @@ def test_quantized_paths_bitwise_identical(semiring, dtype):
     produce bit-identical results on quantized values — the referee
     property the engine's correctness story leans on."""
     rng = np.random.default_rng(3)
-    cols, vals, x, row_map = _problem(rng)
-    R = cols.shape[0]
+    cols, vals, x, tail = _problem(rng)
     q, scale, zero = quantize_edge_vals(vals, dtype)
     qp = jnp.asarray([scale, zero], jnp.float32)
     outs1 = [np.asarray(ell_spmv(
         jnp.asarray(x[:, 0]), jnp.asarray(cols), jnp.asarray(q),
-        jnp.asarray(row_map), R, semiring, use_pallas=up, qparams=qp))
+        *tail, semiring, use_pallas=up, qparams=qp))
         for up in (True, False, "auto")]
     assert np.array_equal(outs1[0], outs1[1])
     assert np.array_equal(outs1[0], outs1[2])
     outsK = [np.asarray(ell_spmv_batch(
         jnp.asarray(x), jnp.asarray(cols), jnp.asarray(q),
-        jnp.asarray(row_map), R, semiring, use_pallas=up, qparams=qp))
+        *tail, semiring, use_pallas=up, qparams=qp))
         for up in (True, False, "auto")]
     assert np.array_equal(outsK[0], outsK[1])
     assert np.array_equal(outsK[0], outsK[2])
@@ -133,22 +136,20 @@ def test_quantized_vs_dequantized_oracle(semiring, dtype, use_pallas):
     (combine ignores the edge value); within 1 ulp for min_plus, where the
     backend single-rounds dequant * scale + src into an FMA."""
     rng = np.random.default_rng(3)
-    cols, vals, x, row_map = _problem(rng)
-    R = cols.shape[0]
+    cols, vals, x, tail = _problem(rng)
     q, scale, zero = quantize_edge_vals(vals, dtype)
     qp = jnp.asarray([scale, zero], jnp.float32)
     vdq = jnp.asarray(dequantize_edge_vals(q, scale, zero))
     out1 = np.asarray(ell_spmv(
         jnp.asarray(x[:, 0]), jnp.asarray(cols), jnp.asarray(q),
-        jnp.asarray(row_map), R, semiring, use_pallas=use_pallas, qparams=qp))
+        *tail, semiring, use_pallas=use_pallas, qparams=qp))
     want1 = np.asarray(ref.ell_spmv_ref(
-        jnp.asarray(x[:, 0]), jnp.asarray(cols), vdq, jnp.asarray(row_map),
-        R, semiring))
+        jnp.asarray(x[:, 0]), jnp.asarray(cols), vdq, *tail, semiring))
     outK = np.asarray(ell_spmv_batch(
         jnp.asarray(x), jnp.asarray(cols), jnp.asarray(q),
-        jnp.asarray(row_map), R, semiring, use_pallas=use_pallas, qparams=qp))
+        *tail, semiring, use_pallas=use_pallas, qparams=qp))
     wantK = np.asarray(ref.ell_spmv_batch_ref(
-        jnp.asarray(x), jnp.asarray(cols), vdq, jnp.asarray(row_map), R,
+        jnp.asarray(x), jnp.asarray(cols), vdq, *tail,
         semiring))
     if semiring == "max_src":
         assert np.array_equal(out1, want1)
@@ -163,16 +164,14 @@ def test_quantized_tolerance_vs_fp32_oracle(dtype):
     """min_plus: the result error vs TRUE fp32 values is bounded by the
     per-edge quantization error (min propagates, never amplifies)."""
     rng = np.random.default_rng(4)
-    cols, vals, x, row_map = _problem(rng)
-    R = cols.shape[0]
+    cols, vals, x, tail = _problem(rng)
     q, scale, zero = quantize_edge_vals(vals, dtype)
     qp = jnp.asarray([scale, zero], jnp.float32)
     out = np.asarray(ell_spmv(jnp.asarray(x[:, 0]), jnp.asarray(cols),
-                              jnp.asarray(q), jnp.asarray(row_map), R,
+                              jnp.asarray(q), *tail,
                               "min_plus", use_pallas=True, qparams=qp))
     want = np.asarray(ref.ell_spmv_ref(jnp.asarray(x[:, 0]), jnp.asarray(cols),
-                                       jnp.asarray(vals), jnp.asarray(row_map),
-                                       R, "min_plus"))
+                                       jnp.asarray(vals), *tail, "min_plus"))
     bound = (scale / 2 if dtype == "int8"
              else 2.0 ** -11 * float(np.abs(vals).max()))
     finite = np.isfinite(want)
@@ -189,16 +188,16 @@ def test_batch_kernel_dequantizes_like_solo(dtype):
     qp = jnp.asarray([scale, zero], jnp.float32)
     vdq = jnp.asarray(dequantize_edge_vals(q, scale, zero))
     safe = np.where(cols >= 0, cols, 0)
-    out = np.asarray(spmv.ell_fold_batch_pallas(
+    out = np.asarray(spmv.ell_fold_pallas(
         jnp.asarray(x.T[:, safe]), jnp.asarray(q), jnp.asarray(cols),
         "min_plus", interpret=True, qparams=qp))
     for k in range(x.shape[1]):
-        solo = spmv.ell_fold_pallas(jnp.asarray(x[safe, k]), jnp.asarray(q),
-                                    jnp.asarray(cols), "min_plus",
-                                    interpret=True, qparams=qp)
-        assert np.array_equal(out[:, k], np.asarray(solo)[:, 0])
-    want = ref.ell_fold_batch_ref(jnp.asarray(x.T[:, safe]), vdq,
-                                  jnp.asarray(cols), "min_plus")
+        solo = spmv.ell_fold_pallas(jnp.asarray(x[safe, k][None]),
+                                    jnp.asarray(q), jnp.asarray(cols),
+                                    "min_plus", interpret=True, qparams=qp)
+        assert np.array_equal(out[k], np.asarray(solo)[0])
+    want = ref.ell_fold_ref(jnp.asarray(x.T[:, safe]), vdq,
+                            jnp.asarray(cols), "min_plus")
     np.testing.assert_allclose(out, np.asarray(want), rtol=3e-7)
 
 
@@ -206,14 +205,12 @@ def test_bfloat16_vals_not_dequantized():
     """bf16 edge values are a compute dtype, not a quantized storage dtype —
     they must pass through the semiring untouched (no qparams arithmetic)."""
     rng = np.random.default_rng(6)
-    cols, vals, x, row_map = _problem(rng)
-    R = cols.shape[0]
+    cols, vals, x, tail = _problem(rng)
     vb = jnp.asarray(vals).astype(jnp.bfloat16)
     xb = jnp.asarray(x[:, 0]).astype(jnp.bfloat16)
-    out = ell_spmv(xb, jnp.asarray(cols), vb, jnp.asarray(row_map), R,
+    out = ell_spmv(xb, jnp.asarray(cols), vb, *tail,
                    "min_plus", use_pallas=True)
-    want = ref.ell_spmv_ref(xb, jnp.asarray(cols), vb, jnp.asarray(row_map),
-                            R, "min_plus")
+    want = ref.ell_spmv_ref(xb, jnp.asarray(cols), vb, *tail, "min_plus")
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), rtol=2e-2)
 

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.core.pipeline import ShardPipeline
-from repro.core.shards import ELLShard
+from repro.core.shards import ELLShard, store_slices
 from repro.core.spans import Counters, span
 
 SPANS = ("graphmp.sweep", "graphmp.schedule", "graphmp.gather",
@@ -226,7 +226,8 @@ class _SlowFetch:
         cols = np.full((8, 128), -1, dtype=np.int32)
         return ELLShard(shard_id=p, start_vertex=0, end_vertex=8, nnz=0,
                         cols=cols, vals=np.zeros((8, 128), np.float32),
-                        row_map=np.zeros(8, np.int32))
+                        row_map=np.full(128, -1, np.int32),
+                        slice_ptr=np.zeros(2, np.int32))
 
 
 @pytest.mark.parametrize("depth", [0, 2])
@@ -251,18 +252,34 @@ def test_stall_and_fetch_keep_their_start_and_end(depth):
 # ---------------------------------------------------------------------------
 # bytes staged to the device
 # ---------------------------------------------------------------------------
-def _shard_bytes(shard: ELLShard) -> int:
-    # cols + vals + row_map, and the two float32 dequantization parameters
-    return shard.cols.nbytes + shard.vals.nbytes + shard.row_map.nbytes + 8
+def _shard_bytes(store, p: int) -> int:
+    # cols + vals + the slice of each row group + row_map padded to the
+    # store's slice count, and the two float32 dequantization parameters
+    shard = store.read_shard(p)
+    row_map = shard.staged_row_map(store_slices(store.properties["shards"]))
+    return (shard.cols.nbytes + shard.vals.nbytes
+            + shard.group_slices().nbytes + row_map.nbytes + 8)
 
 
 @pytest.mark.parametrize("depth", [0, 2])
 def test_h2d_bytes_count_every_staged_shard(traced, depth):
     sess, res, _spans = traced[depth]
-    want = sum(_shard_bytes(sess.store.read_shard(p))
+    want = sum(_shard_bytes(sess.store, p)
                for p in range(sess.store.num_shards))
     for h in res.history:
         assert h.h2d_bytes == want
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+def test_ell_slots_and_arcs_count_every_staged_shard(traced, depth):
+    """Each sweep counts the ELL slots it staged and the edges they hold."""
+    sess, res, _spans = traced[depth]
+    shards = [sess.store.read_shard(p) for p in range(sess.store.num_shards)]
+    for h in res.history:
+        assert h.ell_slots == sum(s.cols.size for s in shards)
+        assert h.ell_arcs == sum(s.nnz for s in shards) \
+            == sess.store.num_edges
+        assert h.ell_slots >= h.ell_arcs
 
 
 def test_h2d_bytes_follow_the_schedule(graph_store, monkeypatch):
@@ -282,7 +299,7 @@ def test_h2d_bytes_follow_the_schedule(graph_store, monkeypatch):
     monkeypatch.setattr(VSWEngine, "_schedule", record)
     sess = GraphSession(graph_store, cache_mode=1, selective_threshold=0.5)
     res = sess.run("sssp", source=0, max_iters=4)
-    sizes = [_shard_bytes(sess.store.read_shard(p))
+    sizes = [_shard_bytes(sess.store, p)
              for p in range(sess.store.num_shards)]
     assert any(h.shards_skipped for h in res.history)
     for h, keep in zip(res.history, schedules):
@@ -303,8 +320,9 @@ def test_attach_hub_exports_stage_seconds_and_h2d_bytes(graph_store):
 
 
 def test_sharded_engine_sums_h2d_bytes_over_its_lanes():
-    """Two devices: each wave ships one [R, W] slice of every array to each
-    device, the wave's largest shard setting R and W."""
+    """Two devices: each wave ships one [L, C] slice of every array to each
+    device, the wave's largest shard setting L and C, the store's largest
+    the slice count S."""
     import os
     import subprocess
     import sys
@@ -316,6 +334,7 @@ def test_sharded_engine_sums_h2d_bytes_over_its_lanes():
         from repro.graph.generate import rmat_edges, materialize
         from repro.graph.storage import write_edge_list
         from repro.graph.preprocess import preprocess_graph
+        from repro.core.shards import GROUP_ROWS, store_slices
         from repro.session import GraphSession
 
         src, dst = materialize(rmat_edges(scale=9, edge_factor=8, seed=7))
@@ -334,10 +353,13 @@ def test_sharded_engine_sums_h2d_bytes_over_its_lanes():
             want = 0
             for w in range(max(len(x) for x in scheds)):
                 sh = [s.store.read_shard(x[w]) for x in scheds if w < len(x)]
-                R = max(x.cols.shape[0] for x in sh)
-                W = max(x.cols.shape[1] for x in sh)
-                # cols, vals, row_map, qparams, start, rows: per device
-                want += D * (R * W * 8 + R * 4 + 8 + 4 + 4)
+                L = max(x.cols.shape[0] for x in sh)
+                C = max(x.cols.shape[1] for x in sh)
+                S = store_slices(s.store.properties["shards"])
+                # cols, vals, slices, row_map, qparams, start, rows: per
+                # device
+                want += D * (L * C * 8 + L // GROUP_ROWS * 4 + S * C * 4
+                             + 8 + 4 + 4)
             for h in res.history:
                 assert h.h2d_bytes == want, (h.h2d_bytes, want)
                 assert 0.0 < h.stage_seconds <= h.fetch_seconds

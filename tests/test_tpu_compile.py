@@ -1,11 +1,13 @@
-"""Compile the SpMV kernels for a described TPU v5e at real widths.
+"""Compile the SpMV kernel and the shard step for a described TPU v5e at
+the benchmark cells' real shapes.
 
 Nothing runs: XLA:TPU and Mosaic compile each kernel for ``v5e:2x2``'s first
 chip from shapes alone, so a kernel the TPU compiler refuses (a layout it
 cannot lower, a block over the VMEM limit) fails here, on a CPU machine,
 instead of on the chip.  Covers every kernel ``ops._pick_path`` can choose
-on TPU: the K=1 fold and the K=16 batched fold in f32 and int8, and the
-dispatching ``ell_spmv`` / ``ell_spmv_batch`` over an n = 2^22 frontier.
+on TPU — the fold at K=1 and K=16 in f32 and int8 — the dispatching
+``ell_spmv`` / ``ell_spmv_batch`` over an n = 2^22 frontier, and the
+engine's ``shard_step`` for PageRank and personalised PageRank.
 
 The topology is described inside a module fixture — never at import — so
 every pytest-xdist worker collects the same tests and only the worker that
@@ -16,12 +18,17 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.apps import get_app
+from repro.core.engine import make_shard_step
+from repro.core.shards import GROUP_ROWS
 from repro.kernels.spmv import ops, spmv
 
 N = 1 << 22      # frontier length: a scale-22 graph
-R = 32768        # ELL rows of one shard at 2^20 edges per shard
+SEGMENTS = 49152  # the destination rows a shard step covers at scale 21/22
 K = 16           # run_batch / GraphService micro-batch width
-WIDTHS = (128, 512)  # the lane-width floor and the default ELL width cap
+# (rows L, slices S) of the cells' shards: almost every shard of 2^20
+# edges, and the smaller last shard of the scale-22 graph
+SHAPES = ((8704, 192), (2944, 48))
 EDGE_DTYPES = {"f32": jnp.float32, "int8": jnp.int8}
 
 
@@ -54,45 +61,75 @@ def _compile(one_chip, fn, *shapes):
 
 
 @pytest.mark.parametrize("edge_dtype", EDGE_DTYPES)
-@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize("width", SHAPES)
 def test_fold_kernel_compiles(one_chip, width, edge_dtype):
+    """The K=1 fold at a shard's real rows (``width`` is its (L, S))."""
+    L, _S = width
+
     def fold(xg, vals, cols, qp):
         return spmv.ell_fold_pallas(xg, vals, cols, "min_plus",
                                     interpret=False, qparams=qp)
 
-    _compile(one_chip, fold, ((R, width), jnp.float32),
-             ((R, width), EDGE_DTYPES[edge_dtype]), ((R, width), jnp.int32),
+    _compile(one_chip, fold, ((1, L, 128), jnp.float32),
+             ((L, 128), EDGE_DTYPES[edge_dtype]), ((L, 128), jnp.int32),
              ((2,), jnp.float32))
 
 
 @pytest.mark.parametrize("semiring", ["min_plus", "plus_src"])
 @pytest.mark.parametrize("edge_dtype", EDGE_DTYPES)
-@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize("width", SHAPES)
 def test_batch_fold_kernel_compiles(one_chip, width, edge_dtype, semiring):
-    def fold(xg, vals, cols, qp):
-        return spmv.ell_fold_batch_pallas(xg, vals, cols, semiring,
-                                          interpret=False, qparams=qp)
+    L, _S = width
 
-    _compile(one_chip, fold, ((K, R, width), jnp.float32),
-             ((R, width), EDGE_DTYPES[edge_dtype]), ((R, width), jnp.int32),
+    def fold(xg, vals, cols, qp):
+        return spmv.ell_fold_pallas(xg, vals, cols, semiring,
+                                    interpret=False, qparams=qp)
+
+    _compile(one_chip, fold, ((K, L, 128), jnp.float32),
+             ((L, 128), EDGE_DTYPES[edge_dtype]), ((L, 128), jnp.int32),
              ((2,), jnp.float32))
 
 
+def _layout_shapes(L, S, edge_dtype=jnp.float32):
+    return (((L, 128), jnp.int32), ((L, 128), edge_dtype),
+            ((L // GROUP_ROWS,), jnp.int32), ((S * 128,), jnp.int32))
+
+
 @pytest.mark.parametrize("k", [1, K])
-@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize("width", SHAPES)
 def test_dispatched_spmv_compiles(one_chip, monkeypatch, width, k):
     """The public ops as the engine calls them under use_pallas="auto",
-    steered onto their TPU branch: XLA gather + segment combine + kernel."""
+    steered onto their TPU branch: XLA gather + kernel + slice combine."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert ops.describe_dispatch("auto", k=k) == "pallas:compiled:gather+fold"
     x_shape = (N,) if k == 1 else (N, k)
     op = ops.ell_spmv if k == 1 else ops.ell_spmv_batch
 
-    def spmv_step(x, cols, vals, row_map):
-        return op(x, cols, vals, row_map, R, "min_plus")
+    def spmv_step(x, cols, vals, slices, row_map):
+        return op(x, cols, vals, slices, row_map, SEGMENTS, "min_plus")
 
     compiled = _compile(one_chip, spmv_step, (x_shape, jnp.float32),
-                        ((R, width), jnp.int32), ((R, width), jnp.float32),
-                        ((R,), jnp.int32))
+                        *_layout_shapes(*width))
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 << 30  # fits one v5e's HBM
+
+
+@pytest.mark.parametrize("app", ["pagerank", "personalized_pagerank"])
+@pytest.mark.parametrize("width", SHAPES)
+def test_shard_step_compiles(one_chip, monkeypatch, width, app):
+    """The engine's whole shard step (``jit_shard_step``: gather, fold,
+    slice combine, post, update) as the cells run it: PageRank at K=1 and
+    personalised PageRank at K=16, on a scale-22 vertex array."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if app == "pagerank":
+        program = get_app("pagerank")
+        vshape, extra = (N + SEGMENTS,), ()
+    else:
+        program = get_app(app, seeds=tuple(range(K)))
+        vshape = (N + SEGMENTS, K)
+        extra = ((vshape, jnp.float32), ((), jnp.int32))
+    step = make_shard_step(program, N, SEGMENTS, "auto",
+                           batched=app != "pagerank")
+    _compile(one_chip, step, (vshape, jnp.float32), (vshape, jnp.float32),
+             (vshape, jnp.float32), *extra, *_layout_shapes(*width),
+             ((2,), jnp.float32), ((), jnp.int32), ((), jnp.int32))
