@@ -24,6 +24,7 @@ import numpy as np
 import xtrace
 
 SWEEP = "graphmp.sweep"
+STEP = "graphmp.step"  # one shard step, with its ``sweep`` and ``shard``
 WAIT = frozenset({"graphmp.wait"})
 ENGINE = frozenset({"graphmp.schedule", "graphmp.gather", "graphmp.step",
                     "graphmp.changed"})
@@ -72,3 +73,21 @@ def idle_shares(trace: xtrace.Trace) -> tuple[float, float] | None:
     both = _idle_under(busy, family(WAIT | ENGINE))
     window_ns = trace.window[1] - trace.window[0]
     return 100.0 * wait / window_ns, 100.0 * (both - wait) / window_ns
+
+
+
+def stepped_shards(trace: xtrace.Trace) -> list[list[int]]:
+    """The shards stepped by each sweep of the traced window that stepped
+    any, in time order: the ``shard`` argument of the ``graphmp.step``
+    spans inside each ``graphmp.sweep`` span (a step span that names no
+    shard, as a multi-device wave's does, is left out)."""
+    lo, hi = trace.window
+    sweeps = sorted(s for name, s, e in trace.host
+                    if name == SWEEP and s >= lo and e <= hi)
+    groups: list[list[int]] = [[] for _ in sweeps]
+    for (name, s, e), args in zip(trace.host, trace.host_args):
+        if name == STEP and "shard" in args and lo <= s and e <= hi:
+            i = int(np.searchsorted(sweeps, s, side="right")) - 1
+            if i >= 0:
+                groups[i].append(int(args["shard"]))
+    return [g for g in groups if g]

@@ -1,8 +1,10 @@
-"""Input edges x columns x sweeps completed in the window, per second of it.
+"""The edges the window's units of work count as done (``Run.work_edges``,
+the application's own count: for PageRank the generated arcs x columns x
+sweeps), per second of the window.
 
-The edge count is the generated graph's |E|, never the engine's own count,
+The count comes from the generated graph, never the engine's own count,
 so a schedule that skips shards reads as faster, not as less work."""
 
 
 def read(run):
-    return run.num_edges * run.columns * run.sweeps / run.window_s
+    return run.work_edges / run.window_s

@@ -1,22 +1,15 @@
-"""Plain NumPy reference for the benchmark's answers, from the edge list alone.
+"""Plain NumPy side of the benchmark's answer check, from the edge list alone.
 
-It imports nothing of the program under test: it reads the edge list the
-benchmark itself wrote (``edges_*.npy``, int64 ``[2, m]`` chunks listed in
-``meta.json``) and iterates pull-mode PageRank in float64:
-
-    r_0 = 1/n everywhere            (personalised: all mass on the seed)
-    r_t = reset + d * sum over in-edges (u, v) of r_{t-1}[u] / outdeg(u)
-
-with ``reset = (1 - d)/n`` (personalised: ``1 - d`` on the seed, 0
-elsewhere); mass on vertices without out-edges is dropped, as the engine
-does.  ``rounding`` rounds the stored vertex values after each step: the
-lower-precision control passes bfloat16 rounding there.
+It imports nothing of the program under test: it writes and reads the edge
+list the benchmark itself makes (``edges_*.npy``, int64 ``[2, m]`` chunks,
+and with edge weights ``weights_*.npy`` float32 beside each, listed in
+``meta.json``), holds the graph for the applications' references
+(``bench/apps``), and compares their answers with the program's.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Callable, Sequence
 
 import ml_dtypes
 import numpy as np
@@ -28,36 +21,50 @@ _TINY = float(np.finfo(np.float32).tiny)
 
 
 def write_edge_list(path: Path, src: np.ndarray, dst: np.ndarray, n: int,
+                    weights: np.ndarray | None = None,
                     chunk: int = 1 << 22) -> None:
-    """The program's edge-list input format: int64 ``[2, m]`` npy chunks and
-    a ``meta.json`` naming them."""
+    """The program's edge-list input format: int64 ``[2, m]`` npy chunks,
+    with ``weights`` a float32 ``weights_XXXXX.npy`` beside each, and a
+    ``meta.json`` naming them."""
     path.mkdir(parents=True, exist_ok=True)
     files = []
     for i, lo in enumerate(range(0, src.size, chunk)):
         name = f"edges_{i:05d}.npy"
         np.save(path / name, np.stack([src[lo:lo + chunk],
                                        dst[lo:lo + chunk]]).astype(np.int64))
+        if weights is not None:
+            np.save(path / _weights_file(name),
+                    weights[lo:lo + chunk].astype(np.float32))
         files.append(name)
     meta = {"num_vertices": int(n), "num_edges": int(src.size),
-            "files": files, "weighted": False}
+            "files": files, "weighted": weights is not None}
     (path / "meta.json").write_text(json.dumps(meta))
 
 
-def read_edge_list(path: Path) -> tuple[np.ndarray, np.ndarray, int]:
-    """(src, dst, n) as written by ``write_edge_list``."""
+def read_edge_list(path: Path
+                   ) -> tuple[np.ndarray, np.ndarray, int, np.ndarray | None]:
+    """(src, dst, n, float32 weights or None) as written by
+    ``write_edge_list``."""
     meta = json.loads((path / "meta.json").read_text())
     m = int(meta["num_edges"])
     src = np.empty(m, np.int32)
     dst = np.empty(m, np.int32)
+    weights = np.empty(m, np.float32) if meta["weighted"] else None
     lo = 0
     for name in meta["files"]:
         arr = np.load(path / name)
         hi = lo + arr.shape[1]
         src[lo:hi], dst[lo:hi] = arr[0], arr[1]
+        if weights is not None:
+            weights[lo:hi] = np.load(path / _weights_file(name))
         lo = hi
     if lo != m:
         raise ValueError(f"edge list holds {lo} edges, meta says {m}")
-    return src, dst, int(meta["num_vertices"])
+    return src, dst, int(meta["num_vertices"]), weights
+
+
+def _weights_file(edges_file: str) -> str:
+    return edges_file.replace("edges_", "weights_")
 
 
 def bf16_rounding(a: np.ndarray) -> np.ndarray:
@@ -66,10 +73,14 @@ def bf16_rounding(a: np.ndarray) -> np.ndarray:
 
 class PullGraph:
     """The in-edges as a sparse count matrix ``A[v, u]`` (duplicate edges
-    counted), for pulling sums along them."""
+    counted), for pulling sums along them; the arcs themselves, with their
+    weights where the graph has them, for references that need more."""
 
-    def __init__(self, src: np.ndarray, dst: np.ndarray, n: int):
+    def __init__(self, src: np.ndarray, dst: np.ndarray, n: int,
+                 weights: np.ndarray | None = None):
         self.n = n
+        self.src, self.dst, self.weights = src, dst, weights
+        self.num_arcs = int(src.size)
         self.a = scipy.sparse.csr_matrix(
             (np.ones(src.size), (dst, src)), shape=(n, n))
         self.inv_out = 1.0 / np.maximum(np.bincount(src, minlength=n), 1)
@@ -79,35 +90,16 @@ class PullGraph:
         return self.a @ x
 
 
-def pagerank(g: PullGraph, iters: int, damping: float,
-             seeds: Sequence[int] | None = None,
-             rounding: Callable[[np.ndarray], np.ndarray] | None = None
-             ) -> np.ndarray:
-    """``[n, K]`` float64: global PageRank (``seeds=None``, K=1) or one
-    personalised column per seed, after ``iters`` steps."""
-    rnd = rounding or (lambda a: a)
-    n = g.n
-    if seeds is None:
-        r = np.full((n, 1), 1.0 / n)
-        reset: float | np.ndarray = (1.0 - damping) / n
-    else:
-        r = np.zeros((n, len(seeds)))
-        r[np.asarray(seeds), np.arange(len(seeds))] = 1.0
-        reset = r * (1.0 - damping)
-    r = rnd(r)
-    for _ in range(iters):
-        x = rnd(r * g.inv_out[:, None])
-        r = rnd(reset + damping * g.pull(x))
-    return r
-
-
 def rel_err(got: np.ndarray, want: np.ndarray) -> np.ndarray:
     """|got - want| / |want| for every vertex and column; where the
-    reference is 0 (no path from a seed yet) the value must be 0 too, and a
+    reference is 0 (no path from a seed yet) the value must be 0 too, a
+    reference +inf (a vertex no search reached) met by +inf is exact, and a
     NaN reads as infinitely wrong."""
     got = np.asarray(got, np.float64)
     if got.shape != want.shape:
         raise ValueError(f"result shape {got.shape} != reference "
                          f"{want.shape}")
-    err = np.abs(got - want) / np.maximum(np.abs(want), _TINY)
+    with np.errstate(invalid="ignore"):
+        err = np.abs(got - want) / np.maximum(np.abs(want), _TINY)
+    err[(want == np.inf) & (got == np.inf)] = 0.0
     return np.nan_to_num(err, nan=np.inf)

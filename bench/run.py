@@ -5,25 +5,44 @@
         --seconds 51 --trace 0
 
 A cell (``workloads`` in ``BENCHMARK.json``) names a configuration
-(``bench/configs/<name>.json``: the graph, how it is preprocessed, the
-session's settings) and a traffic mix (``bench/mixes/<name>.json``: the
-application, its columns, the warm-up, the limits of the answer check).
-Metrics are readers in ``bench/metrics/<name>.py``; peaks are in
-``bench/peaks.json``.  A new cell, configuration, mix or metric is new files
-and new entries in ``BENCHMARK.json``; nothing here names one.
+(``bench/configs/<name>.json``: the graph, whether its edges carry weights,
+how it is preprocessed, the session's settings) and a traffic mix
+(``bench/mixes/<name>.json``: the application, its parameters, the warm-up,
+the limits of the answer check).  The mix's ``app`` is a runner in
+``bench/apps/<app>.py``; metrics are readers in
+``bench/metrics/<name>.py``; peaks are in ``bench/peaks.json``.  A new
+cell, configuration, mix, application or metric is new files and new
+entries in ``BENCHMARK.json``; nothing here names one.
+
+An application module defines ``App``, built as ``App(mix, session, seed,
+out_deg)`` (``out_deg`` counted from the generated arcs), with:
+
+- ``columns``: the vertex columns each engine sweep carries (K);
+- ``edge_value``: whether its semiring reads the edges' values;
+- ``call(units)``: run ``units`` units of work on the session, returning
+  (the values to compare, units done, the engine's ``IterationStats`` of
+  every sweep run, the seconds of each unit).  A unit is the
+  application's own: one engine sweep, or one whole search;
+- ``reference(graph, units, rounding=None)``: the same values from the
+  plain NumPy reference over ``oracle.PullGraph`` for ``units`` units,
+  with ``rounding`` applied to stored values (the lower-precision control);
+- ``work_edges(graph, units)``: the edges those units count as done, the
+  numerator of ``edges_per_s``.
 
 One run:
 
-1. set-up: generate the Graph500 graph on the device from ``--seed``, write
-   it as the program's edge list, ``preprocess_graph`` it, open a
-   ``GraphSession``, and run the cell's application for its warm-up sweeps
-   (compiling its shapes and filling the edge cache);
-2. the window: one ``session.run`` / ``run_batch`` call of M sweeps, M
-   chosen from the median warm-up sweep so that the call lasts about
-   ``--seconds`` (``--trace 1`` records it with the profiler), while a
-   thread samples the process's resident set and the edge cache's bytes;
+1. set-up: generate the Graph500 graph (and its weights) on the device
+   from ``--seed``, write it as the program's edge list,
+   ``preprocess_graph`` it, open a ``GraphSession``, and run the cell's
+   application for its warm-up units (compiling its shapes and filling
+   the edge cache);
+2. the window: one ``call`` of M units, M chosen from the median warm-up
+   unit so that the call lasts about ``--seconds`` (``--trace 1`` records
+   it with the profiler), while a thread samples the process's resident
+   set and the edge cache's bytes.  The mix's ``warmup_units`` runs past
+   the edge cache's fill, so that median is a unit of the steady state;
 3. after the window: the device's peak memory, the session freed, the
-   window's answer compared, every vertex and column, with the NumPy
+   window's answer compared, every value, with the application's NumPy
    reference computed from the edge list alone, and the edge cache's peak
    bytes compared with the configuration's budget.
 
@@ -69,6 +88,7 @@ import roofline  # noqa: E402
 import xtrace  # noqa: E402
 
 SPEC_FILE = ROOT / "BENCHMARK.json"
+APPS = BENCH / "apps"  # one application runner a file, by the mix's app
 CACHE = BENCH / ".cache"  # per-cell work directories (gitignored)
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -107,56 +127,24 @@ def cell_metrics(spec: dict, cell_name: str, trace: bool) -> list[str]:
             if applies(m) and m["moves"] in e2e]
 
 
-def metric_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def _module(kind: str, path: Path):
+    if not path.is_file():
+        raise FileNotFoundError(f"no {kind} {path.stem!r}: {path} does not "
+                                f"exist")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{path.stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-# ---------------------------------------------------------------------------
-# the traffic: one general runner per application a mix names
-# ---------------------------------------------------------------------------
-class PageRankMix:
-    """``app: pagerank``: K=1 is the global PageRank through
-    ``session.run``; K>1 is personalised PageRank through ``run_batch`` over
-    K distinct seed vertices with out-edges (``out_deg``, counted from the
-    generated arcs), drawn from ``--seed``."""
-
-    edge_value = False  # plus_src reads no edge value
-
-    def __init__(self, mix: dict, session, seed: int, out_deg: np.ndarray):
-        self.session = session
-        self.columns = int(mix["columns"])
-        self.damping = float(mix["damping"])
-        self.seeds = None
-        if self.columns > 1:
-            pool = np.flatnonzero(out_deg > 0)
-            rng = np.random.default_rng(seed)
-            self.seeds = [int(v) for v in
-                          rng.choice(pool, size=self.columns, replace=False)]
-
-    def call(self, sweeps: int):
-        """One call of ``sweeps`` sweeps: ([n, K] values, sweeps run,
-        per-sweep IterationStats)."""
-        s = self.session
-        if self.seeds is None:
-            r = s.run("pagerank", damping=self.damping, max_iters=sweeps)
-            return r.values[:, None], r.iterations, r.history
-        s.run_batch("pagerank", sources=self.seeds, damping=self.damping,
-                    max_iters=sweeps)
-        r = s.last_batch_result
-        return r.values, r.iterations, r.history
-
-    def reference(self, graph: oracle.PullGraph, sweeps: int,
-                  rounding=None) -> np.ndarray:
-        return oracle.pagerank(graph, sweeps, self.damping, self.seeds,
-                               rounding)
+def metric_reader(name: str):
+    return _module("metric", BENCH / "metrics" / f"{name}.py").read
 
 
-MIX_APPS = {"pagerank": PageRankMix}
+def app_runner(name: str):
+    """The ``App`` class of the application a mix names."""
+    return _module("app", APPS / f"{name}.py").App
 
 
 # ---------------------------------------------------------------------------
@@ -166,15 +154,18 @@ MIX_APPS = {"pagerank": PageRankMix}
 class Run:
     setup_s: float
     window_s: float
-    sweeps: int              # work units completed in the window
+    sweeps: int              # engine sweeps in the window (len(history))
+    units: int               # the application's units of work completed
+    work_edges: int          # the edges those units count as done
     columns: int
-    num_edges: int           # the generated graph's |E|
+    num_edges: int           # the generated graph's |E|, in arcs
     num_vertices: int
     edge_value: bool
     history: list            # IterationStats of the window's sweeps
     cache_delta: dict        # numeric cache counters, window delta
     peaks: dict
     trace: xtrace.Trace | None = None
+    shard_vertices: list | None = None  # vertices of each shard's interval
 
 
 def rss_bytes() -> int:
@@ -283,13 +274,13 @@ def make_graph(config: dict, seed: int, workdir: Path,
 
     t = time.perf_counter()
     n = 1 << config["scale"]
-    src, dst = graph500.config_arcs(config, seed)
+    src, dst, weights = graph500.config_arcs(config, seed)
     parts["generate_s"] = time.perf_counter() - t
     edges = workdir / "edges"
-    oracle.write_edge_list(edges, src, dst, n)
+    oracle.write_edge_list(edges, src, dst, n, weights)
     m = int(src.size)
     out_deg = np.bincount(src, minlength=n)
-    del src, dst
+    del src, dst, weights
     parts["edge_list_s"] = time.perf_counter() - t - parts["generate_s"]
     t = time.perf_counter()
     graph = workdir / "graph"
@@ -326,13 +317,12 @@ def run_cell(spec: dict, cell: dict, config: dict, mix: dict, *, seed: int,
                                                      parts)
             t = time.perf_counter()
             session = GraphSession(str(graph), **config["session"])
-            app = MIX_APPS[mix["app"]](mix, session, seed, out_deg)
+            app = app_runner(mix["app"])(mix, session, seed, out_deg)
             del out_deg
-            _v, _it, warm = app.call(int(mix["warmup_sweeps"]))
-            del _v
+            _v, _u, _h, warm_s = app.call(int(mix["warmup_units"]))
+            del _v, _h
             parts["warmup_s"] = time.perf_counter() - t
-        warm_s = [h.seconds for h in warm]
-        sweeps = max(1, round(seconds / statistics.median(warm_s)))
+        units = max(1, round(seconds / statistics.median(warm_s)))
 
         trace_dir = workdir / "trace"
         cache0 = _numeric(session.cache_report())
@@ -347,7 +337,7 @@ def run_cell(spec: dict, cell: dict, config: dict, mix: dict, *, seed: int,
             t0 = time.perf_counter()
             setup_s = time.time() - T_START
             with jax.profiler.TraceAnnotation(xtrace.WINDOW_SPAN):
-                values, done, history = app.call(sweeps)
+                values, done, history, unit_s = app.call(units)
             window_s = time.perf_counter() - t0
         if trace:
             jax.profiler.stop_trace()
@@ -359,16 +349,22 @@ def run_cell(spec: dict, cell: dict, config: dict, mix: dict, *, seed: int,
         cache_delta = {k: v - cache0[k] for k, v in _numeric(report).items()
                        if k in cache0}
         shards = session.store.properties["shards"]
+        shard_vertices = np.diff(session.store.properties["intervals"])
         slots = sum(s["rows"] * s["width"] for s in shards)
         use_pallas = session.config.use_pallas
+        arcs = sum(h.ell_arcs for h in history)
+        skipped = sum(h.shards_skipped for h in history)
+        scheduled = skipped + sum(h.shards_processed for h in history)
         _emit("cache", report)
         _emit("window", {
-            "sweeps": done, "sweeps_asked": sweeps, "seconds": window_s,
-            "warmup_sweep_s": warm_s,
+            "units": done, "units_asked": units, "sweeps": len(history),
+            "seconds": window_s, "warmup_unit_s": warm_s, "unit_s": unit_s,
             "sweep_s": [h.seconds for h in history],
             "compiles": window_compiles,
             "setup_compiles": setup_compiles.count, "setup_parts": parts,
             "selective_sweeps": sum(h.selective_enabled for h in history),
+            "shards_skipped_share": skipped / max(1, scheduled),
+            "arcs_skipped_share": 1.0 - arcs / (m * max(1, len(history))),
             "dispatch": describe_dispatch(use_pallas, k=app.columns),
             "shards": len(shards), "padded_slots_per_edge": slots / m,
             "rss_base_gb": rss_base / 1e9,
@@ -386,6 +382,7 @@ def run_cell(spec: dict, cell: dict, config: dict, mix: dict, *, seed: int,
             got = app.reference(ref_graph, done, oracle.bf16_rounding)
         elif control is not None:
             raise ValueError(f"unknown control {control!r}")
+        work_edges = app.work_edges(ref_graph, done)
         del ref_graph
         t_ref = time.perf_counter() - t_ref
         err = oracle.rel_err(got, want)
@@ -397,10 +394,12 @@ def run_cell(spec: dict, cell: dict, config: dict, mix: dict, *, seed: int,
         failed = int(np.count_nonzero(err > limit))
         correct = failed == 0 and sampled.cache_peak_bytes <= budget
 
-        run = Run(setup_s=setup_s, window_s=window_s, sweeps=done,
+        run = Run(setup_s=setup_s, window_s=window_s, sweeps=len(history),
+                  units=done, work_edges=work_edges,
                   columns=app.columns, num_edges=m, num_vertices=n,
                   edge_value=app.edge_value, history=history,
-                  cache_delta=cache_delta, peaks=peaks)
+                  cache_delta=cache_delta, peaks=peaks,
+                  shard_vertices=shard_vertices.tolist())
         result = {"correct": correct, "attempted": int(err.size),
                   "failed": failed}
         t_trace = time.perf_counter()
@@ -409,12 +408,12 @@ def run_cell(spec: dict, cell: dict, config: dict, mix: dict, *, seed: int,
             device["busy_s"] = xtrace.busy_seconds(run.trace)
             device["window_s"] = run.trace.window_s
         metrics = {}
-        units = {mt["name"]: mt["unit"]
-                 for mt in spec["end_to_end"] + spec["per_layer"]}
+        unit_of = {mt["name"]: mt["unit"]
+                   for mt in spec["end_to_end"] + spec["per_layer"]}
         for name in cell_metrics(spec, cell["name"], trace):
             value = metric_reader(name)(run)
             if value is not None:
-                metrics[name] = {"value": value, "unit": units[name]}
+                metrics[name] = {"value": value, "unit": unit_of[name]}
         result.update(metrics=metrics, device=device)
         if trace:
             result["breakdown"] = {"device_ops": xtrace.top_ops(run.trace),

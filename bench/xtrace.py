@@ -6,7 +6,8 @@ Everything is read with ``jax.profiler.ProfileData`` alone:
   line holds one event per operation run, their ``XLA Modules`` line one per
   compiled program run;
 * host planes (``/host:...``) hold the threads' spans, the benchmark's own
-  ``bench.window`` annotation among them, which bounds the traced window.
+  ``bench.window`` annotation among them, which bounds the traced window;
+  the arguments of the program's own spans (``graphmp.*``) are kept.
 
 From these: the seconds the device ran any operation (the union of the op
 intervals inside the window), the device seconds of modules whose name
@@ -23,6 +24,7 @@ from pathlib import Path
 import numpy as np
 
 WINDOW_SPAN = "bench.window"
+PROGRAM_SPANS = "graphmp."  # the prefix of the program's span names
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 _KIND = re.compile(r"kind=(k\w+)")
@@ -38,6 +40,10 @@ class Trace:
     device_ops: dict[str, list]      # device plane -> op events
     device_modules: dict[str, list]  # device plane -> module events
     host: list                       # every host event but the window span
+    # the arguments of each ``host`` event, in the same order: those of the
+    # program's own spans (``PROGRAM_SPANS``), {} for any other; empty for a
+    # trace built without them
+    host_args: list = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -54,7 +60,7 @@ def load(path: str | Path) -> Trace:
     import jax
 
     pd = jax.profiler.ProfileData.from_file(str(path))
-    ops, modules, host = {}, {}, []
+    ops, modules, host, host_args = {}, {}, [], []
     window = None
     for plane in pd.planes:
         if plane.name.startswith("/device:"):
@@ -65,16 +71,21 @@ def load(path: str | Path) -> Trace:
                     modules[plane.name] = _events(line)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                for ev in _events(line):
+                for e in line.events:
+                    ev = (e.name, float(e.start_ns),
+                          float(e.start_ns + e.duration_ns))
                     if ev[0] == WINDOW_SPAN:
                         window = (ev[1], ev[2])
                     else:
                         host.append(ev)
+                        host_args.append(
+                            dict(e.stats)
+                            if e.name.startswith(PROGRAM_SPANS) else {})
     if window is None:
         raise ValueError(f"{path}: no {WINDOW_SPAN!r} span in the trace")
     if not ops:
         raise ValueError(f"{path}: no device plane with an {OPS_LINE!r} line")
-    return Trace(window, ops, modules, host)
+    return Trace(window, ops, modules, host, host_args)
 
 
 def find_xplane(trace_dir: str | Path) -> Path:

@@ -6,7 +6,8 @@ The generator, the byte model, the trace reduction (on a trace recorded on
 a v5e, ``bench/testdata``) and the answer check: every mix's answer matches
 the reference through the harness's own entry points, and the check reads
 ``correct: false`` for the bfloat16 control and for each fault the timed
-path can have on one chip.
+path can have on one chip.  A min-plus application written to a file of
+its own, on a weighted graph, goes through the same run and checks.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ import re
 import subprocess
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import numpy as np
@@ -76,10 +78,10 @@ def test_quadrant_frequencies_match_abcd():
 
 def test_undirected_graph_holds_each_edge_both_ways():
     config = {"scale": 8, "edge_factor": 4, "a": 0.57, "b": 0.19, "c": 0.19,
-              "directed": False}
-    src, dst = graph500.config_arcs(config, 5)
+              "directed": False, "weighted": False}
+    src, dst, weights = graph500.config_arcs(config, 5)
     n, m = 1 << 8, 4 << 8
-    assert src.size == dst.size == 2 * m
+    assert src.size == dst.size == 2 * m and weights is None
     assert np.array_equal(src[m:], dst[:m]) and np.array_equal(dst[m:],
                                                                 src[:m])
     # as a multiset of arcs the graph equals its transpose
@@ -177,9 +179,13 @@ def test_reduction_on_a_synthetic_trace():
 
 
 def _traced_run(trace) -> "run.Run":
-    return run.Run(setup_s=1.0, window_s=0.01, sweeps=2, columns=1,
+    return run.Run(setup_s=1.0, window_s=0.01, sweeps=2, units=2,
+                   work_edges=2000, columns=1,
                    num_edges=1000, num_vertices=100, edge_value=False,
-                   history=[], cache_delta={},
+                   history=[SimpleNamespace(ell_arcs=1000,
+                                            shards_processed=2,
+                                            shards_skipped=0)] * 2,
+                   cache_delta={},
                    peaks={"hbm_bytes_per_s": 1e9, "flops_per_s": 1e12},
                    trace=trace)
 
@@ -187,7 +193,8 @@ def _traced_run(trace) -> "run.Run":
 def test_spmv_roofline_reads_the_shard_step_modules():
     read = run.metric_reader("spmv_roofline")
     tr = _synthetic_trace()
-    # 2 sweeps x (1000 x 8 + 100 x 8) bytes at 1 GB/s over 4 ms of modules
+    # 2 full sweeps x (1000 x 8 + 100 x 8) bytes at 1 GB/s over 4 ms of
+    # modules
     assert read(_traced_run(tr)) == pytest.approx(100 * 17.6e-6 / 0.004)
     assert read(_traced_run(None)) is None
 
@@ -245,11 +252,13 @@ def test_benchmark_json_names_existing_files():
         assert cfg["name"] == c["name"] and "assumed" in cfg
         assert set(c["reduced"]) == set(cfg["reduced"])
     for w in SPEC["workloads"]:
-        assert _NAME.match(w["name"]) and w["chips"] == 1
+        _, _, cfg, mix = run.load_cell(w["name"])
+        # four chips only for a configuration that shards over four devices
+        assert _NAME.match(w["name"]) and w["chips"] in (1, 4)
+        assert w["chips"] == 1 or cfg["session"]["num_devices"] == 4
         assert w["name"] == f"{w['config']}.{w['traffic']}"
-        mix = json.loads((BENCH / "mixes" / f"{w['traffic']}.json")
-                         .read_text())
-        assert mix["app"] in run.MIX_APPS and mix["limits"]["max_rel_err"] > 0
+        assert callable(run.app_runner(mix["app"]))
+        assert mix["limits"]["max_rel_err"] > 0
         e2e = run.cell_metrics(SPEC, w["name"], trace=False)
         assert "setup_s" in e2e and len(e2e) >= 2
         assert run.cell_metrics(SPEC, w["name"], trace=True)
@@ -273,14 +282,17 @@ def test_run_refuses_without_a_tpu():
 # ---------------------------------------------------------------------------
 # whole runs on the CPU at tiny scale
 # ---------------------------------------------------------------------------
-def _tiny_run(tmp_path, monkeypatch, traffic: str, **kw) -> dict:
-    """One run of the mix ``traffic`` on the first cell's configuration cut
-    to a scale-10 graph, several shards, an edge cache smaller than the
-    graph (disk reads and eviction run)."""
+def _tiny_run(tmp_path, monkeypatch, traffic: str | dict, **kw) -> dict:
+    """One run of the mix ``traffic`` (a mix file's name, or the mix itself)
+    on the first cell's configuration cut to a scale-10 graph, several
+    shards, an edge cache smaller than the graph (disk reads and eviction
+    run); weighted where the mix's application reads edge values."""
     monkeypatch.setattr(run, "use_compile_cache", lambda: None)
     _, cell, config, _ = run.load_cell(SPEC["workloads"][0]["name"])
-    mix = json.loads((BENCH / "mixes" / f"{traffic}.json").read_text())
-    config = dict(config, scale=10)
+    mix = traffic if isinstance(traffic, dict) else json.loads(
+        (BENCH / "mixes" / f"{traffic}.json").read_text())
+    weighted = run.app_runner(mix["app"]).edge_value
+    config = dict(config, scale=10, weighted=weighted)
     config["preprocess"] = dict(config["preprocess"],
                                 threshold_edge_num=1 << 12)
     config["session"] = dict(config["session"], cache_budget_bytes=1 << 17)
@@ -371,3 +383,169 @@ def test_cache_over_its_budget_is_not_correct(tmp_path, monkeypatch):
     check = res["checks"]["cache_peak_bytes"]
     assert res["failed"] == 0 and res["correct"] is False
     assert check["value"] > check["limit"]
+
+
+# ---------------------------------------------------------------------------
+# applications found by name
+# ---------------------------------------------------------------------------
+def test_apps_are_found_by_name():
+    app = run.app_runner("pagerank")
+    assert app.edge_value is False and callable(app.call)
+    with pytest.raises(FileNotFoundError, match="no_such_app"):
+        run.app_runner("no_such_app")
+
+
+# A min-plus application that lives outside ``bench/apps``, as a later one
+# is added: SSSP from roots drawn from the seed, one search a unit, against
+# a plain Dijkstra over the weighted edge list.
+MINPLUS = '''
+import heapq
+
+import numpy as np
+
+
+class App:
+    columns = 1
+    edge_value = True
+
+    def __init__(self, mix, session, seed, out_deg):
+        self.session = session
+        pool = np.flatnonzero(out_deg > 0)
+        rng = np.random.default_rng(seed)
+        self.roots = [int(v) for v in rng.choice(pool, size=mix["roots"],
+                                                 replace=False)]
+
+    def _root(self, i):
+        return self.roots[i % len(self.roots)]
+
+    def call(self, units):
+        values, history, seconds = [], [], []
+        for i in range(units):
+            r = self.session.run("sssp", source=self._root(i),
+                                 max_iters=64)
+            values.append(r.values)
+            history += r.history
+            seconds.append(sum(h.seconds for h in r.history))
+        return np.stack(values, 1), units, history, seconds
+
+    def reference(self, graph, units, rounding=None):
+        rnd = rounding or (lambda a: a)
+        out = [[] for _ in range(graph.n)]
+        for u, v, w in zip(graph.src, graph.dst, graph.weights):
+            out[u].append((v, float(w)))
+        cols = []
+        for i in range(units):
+            d = np.full(graph.n, np.inf)
+            d[self._root(i)] = 0.0
+            heap = [(0.0, self._root(i))]
+            while heap:
+                du, u = heapq.heappop(heap)
+                if du > d[u]:
+                    continue
+                for v, w in out[u]:
+                    dv = float(rnd(np.array([du + w]))[0])
+                    if dv < d[v]:
+                        d[v] = dv
+                        heapq.heappush(heap, (dv, v))
+            cols.append(d)
+        return np.stack(cols, 1)
+
+    def work_edges(self, graph, units):
+        return graph.num_arcs * units
+'''
+MINPLUS_MIX = {"app": "minplus", "roots": 4, "warmup_units": 1,
+               "limits": {"max_rel_err": 1e-4}}
+
+
+def _minplus_run(tmp_path, monkeypatch, **kw) -> dict:
+    apps = tmp_path / "apps"
+    apps.mkdir()
+    (apps / "minplus.py").write_text(MINPLUS)
+    monkeypatch.setattr(run, "APPS", apps)
+    return _tiny_run(tmp_path, monkeypatch, MINPLUS_MIX, **kw)
+
+
+def test_weighted_minplus_app_from_its_own_file_is_correct(tmp_path,
+                                                          monkeypatch,
+                                                          capsys):
+    res = _minplus_run(tmp_path, monkeypatch)
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["attempted"] % (1 << 10) == 0 and res["attempted"] > 0
+    assert res["checks"]["max_rel_err"]["value"] <= 1e-4
+    lines = capsys.readouterr().out.splitlines()
+    window = json.loads(next(ln for ln in lines
+                             if ln.startswith("bench window "))[13:])
+    # searches converge: later sweeps skip shards
+    assert window["compiles"] == 0 and window["sweeps"] > window["units"]
+    assert window["shards_skipped_share"] > 0
+    assert 0 < window["arcs_skipped_share"] < 1
+
+
+def test_weighted_minplus_bf16_control_is_not_correct(tmp_path, monkeypatch):
+    res = _minplus_run(tmp_path, monkeypatch, control="bf16")
+    assert res["correct"] is False and res["failed"] > 0
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_weighted_minplus_fault_is_not_correct(tmp_path, monkeypatch, fault):
+    method, wrap = FAULTS[fault]
+    monkeypatch.setattr(VSWEngine, method, wrap(getattr(VSWEngine, method)))
+    res = _minplus_run(tmp_path, monkeypatch)
+    assert res["correct"] is False
+    assert res["checks"]["max_rel_err"]["value"] > \
+        res["checks"]["max_rel_err"]["limit"]
+
+
+# ---------------------------------------------------------------------------
+# what the readers count
+# ---------------------------------------------------------------------------
+def test_rel_err_reads_unreached_as_exact_and_nan_as_wrong():
+    inf, nan = np.inf, np.nan
+    worst = np.finfo(np.float64).max  # nan_to_num's reading of inf
+    want = np.array([[inf, 2.0], [inf, 2.0], [2.0, 2.0], [0.0, 0.0],
+                     [inf, 1.0]])
+    got = np.array([[inf, inf], [3.0, nan], [2.0, 2.0], [0.0, 1e-30],
+                    [nan, -inf]])
+    err = oracle.rel_err(got, want)
+    assert err[0, 0] == 0 and err[2, 0] == 0 and err[3, 0] == 0
+    tiny = float(np.finfo(np.float32).tiny)  # a reference 0 compares absolutely
+    assert err[2, 1] == 0 and err[3, 1] == pytest.approx(1e-30 / tiny)
+    # finite or NaN where the reference is inf; inf, NaN or -inf where it
+    # is finite
+    for v, k in ((1, 0), (4, 0), (0, 1), (1, 1), (4, 1)):
+        assert err[v, k] >= worst, (v, k)
+
+
+def test_spmv_roofline_counts_only_the_shards_stepped():
+    """A converging window over two shards of 70 and 30 vertices: one full
+    sweep (1000 arcs) and one that skipped shard 0 and stepped shard 1's
+    250 arcs; the vertex traffic is that of the intervals stepped."""
+    read = run.metric_reader("spmv_roofline")
+    r = _traced_run(_synthetic_trace())
+    full = read(r)
+    ms = 1e6
+    r.trace.host += [("graphmp.sweep", 0.5 * ms, 4 * ms),
+                     ("graphmp.step", 1 * ms, 2 * ms),
+                     ("graphmp.step", 2 * ms, 3 * ms),
+                     ("graphmp.sweep", 5 * ms, 9.5 * ms),
+                     ("graphmp.step", 6 * ms, 7 * ms)]
+    r.trace.host_args = [{}] * 3 + [{"sweep": 0}, {"sweep": 0, "shard": 0},
+                                    {"sweep": 0, "shard": 1}, {"sweep": 1},
+                                    {"sweep": 1, "shard": 1}]
+    r.shard_vertices = [70, 30]
+    r.history = [SimpleNamespace(ell_arcs=1000, shards_processed=2,
+                                 shards_skipped=0),
+                 SimpleNamespace(ell_arcs=250, shards_processed=1,
+                                 shards_skipped=1)]
+    # (1250 x 8 + (100 + 30) x 8) bytes at 1 GB/s over 4 ms of modules
+    assert read(r) == pytest.approx(100 * 11.04e-6 / 0.004)
+    assert read(r) < full
+    # a sweep that skipped shards with step spans that name none is an error
+    r.trace.host_args = [{}] * len(r.trace.host)
+    with pytest.raises(ValueError, match="graphmp.step"):
+        read(r)
+
+
+def test_edges_per_s_is_the_apps_work_over_the_window():
+    r = _traced_run(None)
+    assert run.metric_reader("edges_per_s")(r) == pytest.approx(2000 / 0.01)

@@ -21,7 +21,9 @@ import run  # noqa: E402
 
 
 def _run(history) -> "run.Run":
-    return run.Run(setup_s=1.0, window_s=0.01, sweeps=len(history), columns=1,
+    return run.Run(setup_s=1.0, window_s=0.01, sweeps=len(history),
+                   units=len(history), work_edges=1000 * len(history),
+                   columns=1,
                    num_edges=1000, num_vertices=100, edge_value=False,
                    history=list(history), cache_delta={},
                    peaks={"hbm_bytes_per_s": 1e9, "flops_per_s": 1e12})

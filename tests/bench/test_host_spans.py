@@ -34,7 +34,8 @@ CHIP = BENCH / "testdata" / "tpu_v5e_pagerank_s14_spans.xplane.pb"
 
 
 def _run(trace=None, history=(), sweeps=2) -> "run.Run":
-    return run.Run(setup_s=1.0, window_s=0.02, sweeps=sweeps, columns=1,
+    return run.Run(setup_s=1.0, window_s=0.02, sweeps=sweeps, units=sweeps,
+                   work_edges=1000 * sweeps, columns=1,
                    num_edges=1000, num_vertices=100, edge_value=False,
                    history=list(history), cache_delta={},
                    peaks={"hbm_bytes_per_s": 1e9, "flops_per_s": 1e12},
@@ -164,7 +165,8 @@ def test_new_metrics_on_a_chip_trace():
     tr = xtrace.load(CHIP)
     hist = [SimpleNamespace(**h) for h in side["history"]]
     r = run.Run(setup_s=0.0, window_s=side["window_s"],
-                sweeps=side["sweeps"], columns=1,
+                sweeps=side["sweeps"], units=side["sweeps"],
+                work_edges=side["num_edges"] * side["sweeps"], columns=1,
                 num_edges=side["num_edges"],
                 num_vertices=side["num_vertices"], edge_value=False,
                 history=hist, cache_delta={},
@@ -187,3 +189,14 @@ def test_new_metrics_on_a_chip_trace():
     assert len(stage) == sum(h.shards_processed for h in hist)
     assert got["pipeline_stage_s_per_sweep"] == pytest.approx(
         sum(stage) * 1e-9 / r.sweeps, abs=1e-3 + 20e-6 * len(stage))
+
+
+def test_stepped_shards_on_a_chip_trace():
+    """Every sweep of the traced chip window steps all 17 shards, each once,
+    and its ``graphmp.step`` spans name them."""
+    side = json.loads(CHIP.with_suffix("").with_suffix(".json").read_text())
+    tr = xtrace.load(CHIP)
+    groups = host_spans.stepped_shards(tr)
+    assert [len(g) for g in groups] == \
+        [h["shards_processed"] for h in side["history"]]
+    assert all(sorted(g) == list(range(17)) for g in groups)
