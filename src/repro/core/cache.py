@@ -213,17 +213,23 @@ class CompressedShardCache:
             return int(self.budget * self.hot_fraction)
         return self.budget if self.mode == 1 else 0
 
+    # The byte counters are read under the lock: inside an operation a
+    # put counts its entry before ``_enforce`` evicts, and a reader on
+    # another thread must see the state between operations, where both
+    # budgets hold.
     @property
     def hot_bytes(self) -> int:
-        if self.adaptive:
-            return self._hot_bytes
-        return self._bytes if self.mode == 1 else 0
+        with self._lock:
+            if self.adaptive:
+                return self._hot_bytes
+            return self._bytes if self.mode == 1 else 0
 
     @property
     def cold_bytes(self) -> int:
-        if self.adaptive:
-            return self._cold_bytes
-        return self._bytes if self.mode in ZSTD_LEVEL else 0
+        with self._lock:
+            if self.adaptive:
+                return self._cold_bytes
+            return self._bytes if self.mode in ZSTD_LEVEL else 0
 
     @property
     def hot_shards(self) -> int:
@@ -239,7 +245,9 @@ class CompressedShardCache:
 
     @property
     def cached_bytes(self) -> int:
-        return self._hot_bytes + self._cold_bytes if self.adaptive else self._bytes
+        with self._lock:
+            return (self._hot_bytes + self._cold_bytes if self.adaptive
+                    else self._bytes)
 
     @property
     def cached_shards(self) -> int:

@@ -730,8 +730,21 @@ class VSWEngine:
         The run **pins the store's graph epoch at start**: every shard fetch
         asserts the shard has not moved past it, and a concurrent
         ``apply()`` therefore raises ``ConcurrentMutationError`` instead of
-        mixing epochs into one result."""
+        mixing epochs into one result.
+
+        The run is one ``graphmp.run`` span with its ``app`` and its
+        ``sources`` (space-separated), so a trace tells short runs, such as
+        SSSP searches, apart."""
         program = self._check_program(program)
+        with span("graphmp.run", app=program.name,
+                  sources=" ".join(map(str, program.sources))):
+            return (yield from self._run_sweeps(
+                program, max_iters, checkpoint_dir, checkpoint_every, resume,
+                init_state))
+
+    def _run_sweeps(self, program, max_iters, checkpoint_dir,
+                    checkpoint_every, resume, init_state):
+        """The body of ``iter_run`` for an already checked ``program``."""
         self._sync_graph_state()
         run_epoch = self._graph_epoch
         shard_epoch_fn = getattr(self.store, "shard_epoch", None)

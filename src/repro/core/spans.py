@@ -18,7 +18,8 @@ The spans, by thread (the engine thread consumes shards, the prefetch
 thread produces them; see ``repro.core.pipeline``), with the counter each
 feeds:
 
-* engine: ``graphmp.sweep`` (one iteration of ``iter_run``;
+* engine: ``graphmp.run`` (one whole ``iter_run``, with its ``app`` and
+  ``sources``), and inside it ``graphmp.sweep`` (one iteration;
   ``IterationStats.seconds``), and inside it ``graphmp.schedule`` (shard
   schedule and frontier ids), ``graphmp.gather`` (the gather dispatch),
   ``graphmp.wait`` (blocked on the next shard; ``stall_seconds``),

@@ -195,6 +195,39 @@ def test_adaptive_churn_hammer_byte_accounting_exact(tier_store, budget_shards):
     cache.audit()
 
 
+def test_byte_counters_read_from_another_thread_never_pass_the_budget(
+        tier_store):
+    """A put counts its entry before ``_enforce`` evicts; a reader on
+    another thread at that moment (a memory sampler) must wait for the
+    operation to end and read a total inside the budget."""
+    from repro.graph.storage import GraphStore
+    store = GraphStore(tier_store.path)
+    cold = [len(CompressedShardCache(store, mode="adaptive",
+                                     budget_bytes=1 << 28)._compress(
+        store.read_shard_bytes(p))) for p in range(store.num_shards)]
+    cache = CompressedShardCache(store, mode="adaptive",
+                                 budget_bytes=2 * max(cold), promote_after=2)
+    enforce, readers, reads = cache._enforce, [], []
+
+    def enforce_with_a_reader():
+        t = threading.Thread(target=lambda: reads.append(
+            cache.cached_bytes))
+        t.start()
+        t.join(0.02)  # the reader has the floor while the put is open
+        readers.append(t)
+        enforce()
+
+    cache._enforce = enforce_with_a_reader
+    for sid in list(range(store.num_shards)) * 2:
+        cache.get(sid)
+    for t in readers:
+        t.join()
+    assert cache.stats.evictions > 0
+    assert len(reads) == len(readers) > 0
+    assert max(reads) <= cache.budget
+    cache.audit()
+
+
 def test_adaptive_ample_budget_misses_once_per_shard(tier_store):
     """With an ample budget the adaptive cache has static-mode economics:
     exactly one miss (and one canonical-size disk charge) per shard."""

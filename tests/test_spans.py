@@ -306,6 +306,32 @@ def test_h2d_bytes_follow_the_schedule(graph_store, monkeypatch):
         assert h.h2d_bytes == sum(sizes[p] for p in keep)
 
 
+def test_each_run_is_one_span_naming_its_sources(graph_store, tmp_path):
+    """Searches through one shared engine: one ``graphmp.run`` span a
+    search, naming its app and source and holding its sweeps; a batched
+    run names all its sources."""
+    from repro.session import GraphSession
+
+    sess = GraphSession(graph_store)
+    sess.run("sssp", source=0, max_iters=2)  # compile outside the trace
+    sess.run_batch("sssp", sources=[0, 1], max_iters=2)
+    sources = [1, 2, 3]
+    with jax.profiler.trace(str(tmp_path / "t")):
+        results = [sess.run("sssp", source=s) for s in sources]
+        sess.run_batch("sssp", sources=[4, 5], max_iters=2)
+    sess.close()
+    spans = _read_spans(tmp_path / "t")
+    runs = sorted(spans.by_name["graphmp.run"], key=lambda ev: ev[1])
+    assert [(a["app"], a["sources"]) for *_, a in runs] == [
+        ("sssp", 1), ("sssp", 2), ("sssp", 3), ("sssp_multi", "4 5")]
+    sweeps = spans.by_name["graphmp.sweep"]
+    for (line, s, e, _), res in zip(runs, results):
+        inside = [sw for sw in sweeps if s <= sw[1] and sw[2] <= e]
+        assert all(sw[0] == line for sw in inside)
+        # the last sweep span of a converged run may find nothing to step
+        assert res.iterations <= len(inside) <= res.iterations + 1
+
+
 def test_attach_hub_exports_stage_seconds_and_h2d_bytes(graph_store):
     from repro.obs import MetricsHub
     from repro.session import GraphSession

@@ -114,22 +114,24 @@ def test_dispatched_spmv_compiles(one_chip, monkeypatch, width, k):
     assert mem.temp_size_in_bytes < 16 << 30  # fits one v5e's HBM
 
 
-@pytest.mark.parametrize("app", ["pagerank", "personalized_pagerank"])
+@pytest.mark.parametrize("app", ["pagerank", "personalized_pagerank",
+                                 "sssp"])
 @pytest.mark.parametrize("width", SHAPES)
 def test_shard_step_compiles(one_chip, monkeypatch, width, app):
     """The engine's whole shard step (``jit_shard_step``: gather, fold,
-    slice combine, post, update) as the cells run it: PageRank at K=1 and
-    personalised PageRank at K=16, on a scale-22 vertex array."""
+    slice combine, post, update) as the cells run it: PageRank at K=1,
+    personalised PageRank at K=16 and SSSP at K=1 (``min_plus`` over
+    float32 edge values), on a scale-22 vertex array."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if app == "pagerank":
-        program = get_app("pagerank")
+    if app in ("pagerank", "sssp"):
+        program = get_app(app)
         vshape, extra = (N + SEGMENTS,), ()
     else:
         program = get_app(app, seeds=tuple(range(K)))
         vshape = (N + SEGMENTS, K)
         extra = ((vshape, jnp.float32), ((), jnp.int32))
     step = make_shard_step(program, N, SEGMENTS, "auto",
-                           batched=app != "pagerank")
+                           batched=app == "personalized_pagerank")
     _compile(one_chip, step, (vshape, jnp.float32), (vshape, jnp.float32),
              (vshape, jnp.float32), *extra, *_layout_shapes(*width),
              ((2,), jnp.float32), ((), jnp.int32), ((), jnp.int32))
